@@ -70,6 +70,9 @@ def main() -> None:
     ap.add_argument("--out", default="BENCH_RESULTS.json",
                     help="merged JSON output path ('' disables)")
     args = ap.parse_args()
+    from repro.runtime.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     from . import common
 

@@ -40,15 +40,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-try:  # jax ≥ 0.5 top-level API; older releases ship it under experimental
-    _shard_map = jax.shard_map
-except AttributeError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# pvary (varying-axis annotation) only exists on newer jax; on older
-# shard_map it is unnecessary — replicated operands are implicitly varying
-_pvary = getattr(jax.lax, "pvary", lambda x, axes: x)
-
 from . import clustering as _cl
 from . import game as _game
 from . import postprocess as _post
@@ -66,7 +57,7 @@ def _shard_cluster(src_sh, dst_sh, n_vertices, xi, kappa, axis):
     deg = jax.lax.psum(deg.astype(jnp.int32), axis)  # global degrees
     state = _cl.init_state(n_vertices)
     # the scan carry diverges per shard: mark it device-varying up front
-    state = jax.tree.map(lambda x: _pvary(x, (axis,)), state)
+    state = jax.tree.map(lambda x: jax.lax.pcast(x, axis, to="varying"), state)
     state = _cl.cluster_chunk(state, src_sh[0], dst_sh[0], deg, xi=xi, kappa=kappa)
     return (
         state.v2c_h[None],
@@ -99,7 +90,7 @@ def distributed_partition(src, dst, n_vertices: int, config: S5PConfig, mesh,
 
     # ---- Phase 1: sharded clustering ----
     spec = P(axis)
-    fn = _shard_map(
+    fn = jax.shard_map(
         partial(_shard_cluster, n_vertices=n_vertices, xi=xi, kappa=kappa, axis=axis),
         mesh=mesh,
         in_specs=(spec, spec),

@@ -87,14 +87,19 @@ class AssignCarry(PartitionerCarry):
     def init(self) -> jax.Array:
         return jnp.zeros((self.k,), jnp.int32)
 
+    def _kernel_path(self, chunk_size):
+        # lazy import (core.baselines ↔ kernels layering, see clustering)
+        from ..kernels import stream_scan as _scan
+
+        return self._use_kernel and _scan.select_path(
+            0, self.k, chunk_size, consumer="assign",
+            budget=self._vmem_budget) == "fused"
+
     def step_chunk(self, carry, src, dst, n_valid, *extras):
         h, a, b = extras
-        if self._use_kernel:
-            # lazy import (core.baselines ↔ kernels layering, see clustering)
+        if self._kernel_path(src.shape[0]):
             from ..kernels import stream_scan as _scan
 
-            _scan.select_path(0, self.k, src.shape[0], consumer="assign",
-                              budget=self._vmem_budget)  # path logging
             parts, load = _scan.assign_scan(
                 carry, src, dst, h, self.c2p[a], self.c2p[b],
                 max_load=self.max_load)
@@ -104,7 +109,7 @@ class AssignCarry(PartitionerCarry):
         return load, parts
 
     def retract_chunk(self, carry, src, dst, n_valid, parts, *extras):
-        if self._use_kernel:
+        if self._kernel_path(src.shape[0]):
             from ..kernels import stream_scan as _scan
 
             zeros = jnp.zeros_like(src)

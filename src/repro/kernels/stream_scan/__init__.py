@@ -8,22 +8,28 @@ seed ``lax.scan`` oracles (bit-identical contract).
 
 from .kernel import (  # noqa: F401
     DEFAULT_BLOCK,
+    LANES,
     assign_scan,
+    cluster_leaf_shapes,
     cluster_scan,
     dispatch_count,
     reset_dispatch_count,
     scoring_scan,
     stream_scan_tpu,
+    table_width,
 )
 from .ops import (  # noqa: F401
     DEFAULT_VMEM_BUDGET,
+    SMEM_BYTES,
     VMEM_BUDGET_ENV,
     GreedyCarry,
     GridCarry,
     HdrfCarry,
+    assign_state_bytes,
     cluster_state_bytes,
     kernel_fits,
     make_chunk_fn,
+    paths_taken,
     reset_path_log,
     scoring_state_bytes,
     select_path,

@@ -1,33 +1,39 @@
-"""Pallas TPU megakernel: the whole S5P chunk step in one dispatch.
+"""Pallas TPU megakernels: the whole S5P chunk step in one dispatch.
 
 One ``pallas_call`` per stream chunk covers the entire inner loop of the
-streaming partitioners — insert *and* retract.  Layout (the
-``PrefetchScalarGridSpec`` pipelining idiom):
+streaming partitioners — insert *and* retract.  Layout, as the TPU
+compiler lays it out:
 
-- the grid is blocked over the chunk's edges (``block`` edges per step);
-  per-edge operands (recorded parts in, parts out) ride blocked
-  ``BlockSpec``s so the pipeline double-buffers their DMA, while the edge
-  endpoint ids are **scalar-prefetched** (SMEM) — the serial scan indexes
-  them with scalar loads ahead of the compute stream;
-- revisited state (the load vector, the **counted** replica table, HDRF
-  partial degrees) lives in VMEM blocks with constant index maps, so it
-  stays resident across grid steps and is written back once;
-- ``input_output_aliases`` donates every state input to its output, so a
-  dispatch updates state in place instead of copying it per call;
+- the grid is blocked over the chunk's edges (``block`` edges per step).
+  Every per-edge operand (endpoint ids, recorded parts, Alg.-3 extras) and
+  the parts output ride **blocked SMEM** specs, so the pipeline DMAs them
+  one block at a time and the serial scan reads them with scalar loads.
+  SMEM use is therefore set by ``block``, not by the chunk length.  1-D
+  int32 arrays tile by 1024 on the chip, so ``block`` is 1024 (or the
+  whole chunk, when it is shorter);
+- the only scalar prefetch is the ``meta`` vector (limit, sign, cap);
+- the greedy/HDRF per-vertex state is **one packed int32 row per vertex**,
+  ``W = roundup(k + [hdrf], 128)`` lanes wide: lanes ``[0, k)`` hold the
+  counted replica table, lane ``k`` holds HDRF's partial degree, the rest
+  is zero.  The table stays in HBM (``memory_space=ANY``, donated in
+  place through ``input_output_aliases``).  The **fused** rung DMAs the
+  whole table into one VMEM scratch at grid step 0 and back at the last
+  step; the **tiled** rung DMAs the two endpoint rows of each edge into
+  two ``(1, W)`` VMEM buffers and back.  A row DMA must be 128-lane
+  aligned, which is what fixes ``W``;
+- Algorithm 1's state is all scalar and indexed by data, so it lives in
+  SMEM scratch (DMA'd in at step 0, out at the last step);
+- Algorithm 3 keeps its load vector as a ``(1, W)`` VMEM block;
 - a ``sign`` operand (+1 insert / -1 retract) reuses the same kernel for
   deletion: the counted replica table is an abelian group, so retraction
   is the same scatter arithmetic with negated weights and the recorded
   per-edge parts standing in for the scored pick.
 
-Three kernels share the layout: the greedy/HDRF scoring scan
-(:func:`scoring_scan`), the Algorithm-1 clustering fold
-(:func:`cluster_scan`), and the Algorithm-3 placement pass
-(:func:`assign_scan`).  When the per-vertex state exceeds the VMEM
-budget, :func:`scoring_scan` switches to a **tiled** variant: the replica
-table (and partial degrees) stay HBM-resident (``memory_space=ANY``) and
-the kernel gathers/scatters single rows with ``pl.load`` / ``pl.store``
-— slower per edge, still one dispatch per chunk.  ``ops.py`` owns the
-fused → tiled → oracle degradation ladder.
+Mosaic has no int32 argmin/argmax and cannot store a scalar to VMEM, so
+every pick is ``m = min(x); min(where(x == m, lane, W))`` — the first
+index, exactly argmin's tie-break — and every per-vertex write is a row
+store or an SMEM scalar store.  ``ops.py`` owns the fused → tiled →
+oracle ladder and the byte counts that gate it.
 
 Per-edge math mirrors ``ref.py`` (and ``core.clustering`` /
 ``core.postprocess``) expression-for-expression, so interpret mode is
@@ -51,16 +57,21 @@ from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
     "DEFAULT_BLOCK",
+    "LANES",
     "assign_scan",
+    "cluster_leaf_shapes",
     "cluster_scan",
     "dispatch_count",
     "reset_dispatch_count",
     "scoring_scan",
     "stream_scan_tpu",
+    "table_width",
 ]
 
-DEFAULT_BLOCK = 512
+DEFAULT_BLOCK = 1024  # 1-D int32 arrays tile by 1024 on the chip
+LANES = 128
 _INF_I32 = 2**30  # python int: jnp constants may not be captured by kernels
+_MAX_I32 = 2**31 - 1
 
 # Dispatch accounting: one increment per pallas_call issued.  The bench
 # uses this to demonstrate the 1-dispatch-per-chunk contract (the oracle
@@ -80,6 +91,13 @@ def reset_dispatch_count() -> None:
 def _bump_dispatch() -> None:
     global _DISPATCHES
     _DISPATCHES += 1
+
+
+def table_width(k: int, mode: str) -> int:
+    """Lanes of the packed per-vertex row: k replica counters, plus HDRF's
+    partial degree, rounded up to whole 128-lane tiles."""
+    need = k + (1 if mode == "hdrf" else 0)
+    return -(-need // LANES) * LANES
 
 
 def _resolve(block, n, interpret):
@@ -106,14 +124,38 @@ def _pad_edges(src, dst, parts, pad):
 
 
 def _edge_spec(block):
-    return pl.BlockSpec((block,), lambda i, *_: (i,))
+    return pl.BlockSpec((block,), lambda i, *_: (i,),
+                        memory_space=pltpu.SMEM)
 
 
 def _const_spec(shape):
     return pl.BlockSpec(shape, lambda i, *_: tuple(0 for _ in shape))
 
 
-_ANY_SPEC = pl.BlockSpec(memory_space=pltpu.ANY)
+_ANY_SPEC = pl.BlockSpec(memory_space=pl.ANY)
+
+
+def _copy(src, dst, sem):
+    cp = pltpu.make_async_copy(src, dst, sem)
+    cp.start()
+    cp.wait()
+
+
+def _first_min(x, lane, width):
+    """Index of the first minimum of a (1, W) int32 row (argmin's
+    tie-break) without an int32 argmin."""
+    m = jnp.min(x)
+    return jnp.min(jnp.where(x == m, lane, width))
+
+
+def _any(mask):
+    return jnp.max(jnp.where(mask, 1, 0)) > 0
+
+
+def _compiler_params(vmem_limit):
+    if vmem_limit is None:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=int(vmem_limit))
 
 
 # ===================================================================
@@ -121,152 +163,177 @@ _ANY_SPEC = pl.BlockSpec(memory_space=pltpu.ANY)
 # ===================================================================
 
 
-def _scoring_kernel(meta_ref, src_ref, dst_ref, pin_ref, *refs,
-                    mode, eps, k, block, tiled):
-    if mode == "hdrf":
-        (load_in, _rep_in, _pd_in, lam_in,
-         parts_ref, load_ref, rep_ref, pd_ref) = refs
+def _scoring_kernel(meta_ref, src_ref, dst_ref, pin_ref, load_in, tab_in,
+                    *refs, mode, eps, k, block, tiled):
+    hdrf = mode == "hdrf"
+    if hdrf:
+        lam_ref, parts_ref, load_ref, tab_ref, *scratch = refs
     else:
-        load_in, _rep_in, parts_ref, load_ref, rep_ref = refs
-        pd_ref = lam_in = None
+        parts_ref, load_ref, tab_ref, *scratch = refs
+        lam_ref = None
+    if tiled:
+        buf_u, buf_v, sem = scratch
+    else:
+        tab_v, sem = scratch
+    W = load_ref.shape[1]
     i = pl.program_id(0)
 
     @pl.when(i == 0)
     def _():
         load_ref[...] = load_in[...]
         if not tiled:
-            rep_ref[...] = _rep_in[...]
-            if mode == "hdrf":
-                pd_ref[...] = _pd_in[...]
+            _copy(tab_in, tab_v, sem.at[0])
 
     limit = meta_ref[0]
     sign = meta_ref[1]
-    iota = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)[0, :]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, W), 1)
+    real_lane = lane < k
 
-    def row(ref, u):
-        if tiled:
-            return pl.load(ref, (pl.dslice(u, 1), slice(None)))[0, :]
-        return ref[u, :]
+    def gather(u, v):
+        if not tiled:
+            return tab_v[pl.ds(u, 1), :], tab_v[pl.ds(v, 1), :]
+        cu = pltpu.make_async_copy(tab_ref.at[pl.ds(u, 1), :], buf_u,
+                                   sem.at[0])
+        cv = pltpu.make_async_copy(tab_ref.at[pl.ds(v, 1), :], buf_v,
+                                   sem.at[1])
+        cu.start()
+        cv.start()
+        cu.wait()
+        cv.wait()
+        return buf_u[...], buf_v[...]
 
-    def row_add(ref, u, delta):
-        if tiled:
-            fresh = pl.load(ref, (pl.dslice(u, 1), slice(None)))[0, :]
-            pl.store(ref, (pl.dslice(u, 1), slice(None)),
-                     (fresh + delta)[None, :])
-        else:
-            ref[u, :] = ref[u, :] + delta
-
-    def scalar_add(ref, u, delta):
-        if tiled:
-            fresh = pl.load(ref, (pl.dslice(u, 1), slice(None)))[0, 0]
-            pl.store(ref, (pl.dslice(u, 1), slice(None)),
-                     (fresh + delta)[None, None])
-        else:
-            ref[u, 0] = ref[u, 0] + delta
-
-    def scalar_get(ref, u):
-        if tiled:
-            return pl.load(ref, (pl.dslice(u, 1), slice(None)))[0, 0]
-        return ref[u, 0]
+    def scatter(u, v, new_u, new_v):
+        # u == v writes the same row twice with the same value
+        if not tiled:
+            tab_v[pl.ds(u, 1), :] = new_u
+            tab_v[pl.ds(v, 1), :] = new_v
+            return
+        buf_u[...] = new_u
+        buf_v[...] = new_v
+        cu = pltpu.make_async_copy(buf_u, tab_ref.at[pl.ds(u, 1), :],
+                                   sem.at[0])
+        cv = pltpu.make_async_copy(buf_v, tab_ref.at[pl.ds(v, 1), :],
+                                   sem.at[1])
+        cu.start()
+        cv.start()
+        cu.wait()
+        cv.wait()
 
     def body(e, _):
         g = i * block + e
-        u = src_ref[g]
-        v = dst_ref[g]
+        u = src_ref[e]
+        v = dst_ref[e]
         real = g < limit
         is_ins = sign > 0
         p_ret = pin_ref[e]
-        load = load_ref[0, :]
-        if mode == "hdrf":
+        same = u == v
+        row_u, row_v = gather(u, v)
+        load = load_ref[...]
+        if hdrf:
             # the oracle bumps pd unconditionally (self-loops and the
-            # chunk's own padding included) *before* scoring
-            pdw = jnp.where(real, sign, 0)
-            scalar_add(pd_ref, u, pdw)
-            scalar_add(pd_ref, v, pdw)
-            du = scalar_get(pd_ref, u).astype(jnp.float32)
-            dv = scalar_get(pd_ref, v).astype(jnp.float32)
-            ru = row(rep_ref, u) > 0
-            rv = row(rep_ref, v) > 0
+            # chunk's own padding included) *before* scoring; a self-loop
+            # bumps the one row twice
+            pdw = jnp.where(real, sign, 0) * jnp.where(same, 2, 1)
+            bump = jnp.where(lane == k, pdw, 0)
+            row_u = row_u + bump
+            row_v = row_v + bump
+            du = jnp.sum(jnp.where(lane == k, row_u, 0), axis=1,
+                         keepdims=True).astype(jnp.float32)
+            dv = jnp.sum(jnp.where(lane == k, row_v, 0), axis=1,
+                         keepdims=True).astype(jnp.float32)
+            ru = (row_u > 0) & real_lane
+            rv = (row_v > 0) & real_lane
             theta_u = du / (du + dv)
             theta_v = 1.0 - theta_u
             g_u = jnp.where(ru, 1.0 + (1.0 - theta_u), 0.0)
             g_v = jnp.where(rv, 1.0 + (1.0 - theta_v), 0.0)
             loadf = load.astype(jnp.float32)
-            maxl = jnp.max(loadf)
-            minl = jnp.min(loadf)
-            bal = (maxl - loadf) / (eps + maxl - minl)
-            score = g_u + g_v + lam_in[0, 0] * bal
-            pick_ins = jnp.argmax(score).astype(jnp.int32)
+            maxl = jnp.max(jnp.where(real_lane, loadf, -jnp.inf))
+            minl = jnp.min(jnp.where(real_lane, loadf, jnp.inf))
+            den = eps + maxl - minl  # as ref.hdrf_chunk: 0 on a full tie
+            bal = (maxl - loadf) / jnp.where(den > 0, den, 1.0)
+            score = g_u + g_v + lam_ref[...] * bal
+            score = jnp.where(real_lane, score, -jnp.inf)
+            best = jnp.max(score)
+            pick_ins = jnp.min(jnp.where(score == best, lane, W))
         else:
-            ru = row(rep_ref, u) > 0
-            rv = row(rep_ref, v) > 0
+            ru = (row_u > 0) & real_lane
+            rv = (row_v > 0) & real_lane
             both = ru & rv
             either = ru | rv
-            case1 = jnp.any(both)
-            case2 = jnp.any(ru) & jnp.any(rv)
-            case3 = jnp.any(either)
+            case1 = _any(both)
+            case2 = _any(ru) & _any(rv)
+            case3 = _any(either)
+            # Mosaic cannot select between bool vectors: select int rows
+            both_i = jnp.where(both, 1, 0)
+            either_i = jnp.where(either, 1, 0)
             mask = jnp.where(
-                case1, both,
-                jnp.where(case2, either, jnp.where(case3, either, True)))
-            score = jnp.where(mask, load, _INF_I32)
-            pick_ins = jnp.argmin(score).astype(jnp.int32)
+                case1, both_i,
+                jnp.where(case2, either_i, jnp.where(case3, either_i, 1)))
+            score = jnp.where(mask > 0, load, _INF_I32)
+            score = jnp.where(real_lane, score, _MAX_I32)
+            pick_ins = _first_min(score, lane, W)
         pick = jnp.where(is_ins, pick_ins, jnp.maximum(p_ret, 0))
-        placed = real & (u != v) & jnp.where(is_ins, True, p_ret >= 0)
+        placed = real & (~same) & jnp.where(is_ins, True, p_ret >= 0)
         w = jnp.where(placed, sign, 0)
-        hit = jnp.where(iota == pick, w, 0)
-        load_ref[0, :] = load + hit
-        row_add(rep_ref, u, hit)
-        row_add(rep_ref, v, hit)
+        hit = jnp.where(lane == pick, w, 0)
+        load_ref[...] = load + hit
+        scatter(u, v, row_u + hit, row_v + hit)
         parts_ref[e] = jnp.where(
-            is_ins, jnp.where(real & (u != v), pick_ins, -1), p_ret)
+            is_ins, jnp.where(real & (~same), pick_ins, -1), p_ret)
         return 0
 
     jax.lax.fori_loop(0, block, body, 0)
 
+    if not tiled:
+        @pl.when(i == pl.num_programs(0) - 1)
+        def _():
+            _copy(tab_v, tab_ref, sem.at[0])
+
 
 @functools.partial(jax.jit,
-                   static_argnames=("mode", "eps", "block", "tiled",
-                                    "interpret"))
-def _scoring_call(meta, src, dst, pin, *state, mode, eps, block, tiled,
-                  interpret):
+                   static_argnames=("mode", "eps", "k", "block", "tiled",
+                                    "vmem_limit", "interpret"))
+def _scoring_call(meta, src, dst, pin, load, table, *lam, mode, eps, k,
+                  block, tiled, vmem_limit, interpret):
     Epad = src.shape[0]
-    V, k = state[1].shape
-    table = _ANY_SPEC if tiled else _const_spec((V, k))
-    col = _ANY_SPEC if tiled else _const_spec((V, 1))
-    in_specs = [_edge_spec(block), _const_spec((1, k)), table]
-    out_specs = [_edge_spec(block), _const_spec((1, k)), table]
-    out_shape = [
-        jax.ShapeDtypeStruct((Epad,), jnp.int32),
-        jax.ShapeDtypeStruct((1, k), jnp.int32),
-        jax.ShapeDtypeStruct((V, k), jnp.int32),
-    ]
-    # aliasing indices count the scalar-prefetch args (meta, src, dst)
-    aliases = {3: 0, 4: 1, 5: 2}
+    V, W = table.shape
+    edge = _edge_spec(block)
+    in_specs = [edge, edge, edge, _const_spec((1, W)), _ANY_SPEC]
     if mode == "hdrf":
-        in_specs += [col, _const_spec((1, 1))]
-        out_specs += [col]
-        out_shape += [jax.ShapeDtypeStruct((V, 1), jnp.int32)]
-        aliases[6] = 3
+        in_specs.append(_const_spec((1, 1)))
+    if tiled:
+        scratch = [pltpu.VMEM((1, W), jnp.int32),
+                   pltpu.VMEM((1, W), jnp.int32),
+                   pltpu.SemaphoreType.DMA((2,))]
+    else:
+        scratch = [pltpu.VMEM((V, W), jnp.int32),
+                   pltpu.SemaphoreType.DMA((1,))]
     kernel = functools.partial(_scoring_kernel, mode=mode, eps=eps, k=k,
                                block=block, tiled=tiled)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=1,
         grid=(Epad // block,),
         in_specs=in_specs,
-        out_specs=out_specs,
+        out_specs=[edge, _const_spec((1, W)), _ANY_SPEC],
+        scratch_shapes=scratch,
     )
+    # aliasing indices count the scalar-prefetch arg (meta)
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=out_shape,
-        input_output_aliases=aliases,
+        out_shape=[jax.ShapeDtypeStruct(s, jnp.int32)
+                   for s in ((Epad,), (1, W), (V, W))],
+        input_output_aliases={4: 1, 5: 2},
+        compiler_params=_compiler_params(vmem_limit),
         interpret=interpret,
-    )(meta, src, dst, pin, *state)
+    )(meta, src, dst, pin, load, table, *lam)
 
 
 def scoring_scan(src, dst, load, rep, pd=None, lam=None, *, mode: str,
                  sign: int = 1, parts=None, n_valid=None, eps: float = 1e-3,
                  block: int | None = None, tiled: bool = False,
+                 vmem_limit: int | None = None,
                  interpret: bool | None = None):
     """One fused greedy/HDRF chunk — insert (``sign=+1``) or retract
     (``sign=-1``, with the recorded per-edge ``parts`` and ``n_valid``).
@@ -274,8 +341,9 @@ def scoring_scan(src, dst, load, rep, pd=None, lam=None, *, mode: str,
     src/dst: (E,) int32; load: (k,) int32; rep: (V, k) int32 **counted**
     replica table; pd: (V,) int32 partial degrees (HDRF only); lam:
     scalar f32.  Returns ``(parts (E,), load, rep, pd)`` (``pd`` None for
-    greedy).  ``tiled=True`` keeps rep/pd HBM-resident (``ANY``) for
-    tables past the VMEM budget.
+    greedy).  ``tiled=True`` keeps the packed table in HBM and moves one
+    row per endpoint; ``vmem_limit`` raises the compiler's VMEM limit for
+    a fused table larger than its default.
     """
     if mode not in ("greedy", "hdrf"):
         raise ValueError(f"unknown mode {mode!r}")
@@ -297,18 +365,19 @@ def scoring_scan(src, dst, load, rep, pd=None, lam=None, *, mode: str,
     src, dst, pin = _pad_edges(src, dst, parts, pad)
     limit = jnp.asarray(E if sign > 0 else n_valid, jnp.int32)
     meta = jnp.stack([limit, jnp.int32(sign)])
-    state = (load.reshape(1, k), rep)
+    W = table_width(k, mode)
+    table = jnp.pad(rep, ((0, 0), (0, W - k)))
+    lam_arg = ()
     if mode == "hdrf":
-        state += (pd.reshape(V, 1), jnp.asarray(lam, jnp.float32).reshape(1, 1))
+        table = table.at[:, k].set(pd)
+        lam_arg = (jnp.asarray(lam, jnp.float32).reshape(1, 1),)
     _bump_dispatch()
-    out = _scoring_call(meta, src, dst, pin, *state, mode=mode,
-                        eps=float(eps), block=blk, tiled=bool(tiled),
-                        interpret=interpret)
-    if mode == "hdrf":
-        parts_out, load2, rep2, pd2 = out
-        return parts_out[:E], load2[0], rep2, pd2[:, 0]
-    parts_out, load2, rep2 = out
-    return parts_out[:E], load2[0], rep2, None
+    parts_out, load2, table2 = _scoring_call(
+        meta, src, dst, pin, jnp.pad(load, (0, W - k)).reshape(1, W), table,
+        *lam_arg, mode=mode, eps=float(eps), k=k, block=blk,
+        tiled=bool(tiled), vmem_limit=vmem_limit, interpret=interpret)
+    pd2 = table2[:, k] if mode == "hdrf" else None
+    return parts_out[:E], load2[0, :k], table2[:, :k], pd2
 
 
 def stream_scan_tpu(src, dst, load, rep, pd, lam, *, mode: str,
@@ -332,57 +401,62 @@ def stream_scan_tpu(src, dst, load, rep, pd, lam, *, mode: str,
 # Algorithm 1 clustering fold
 # ===================================================================
 
+_CLUSTER_LEAVES = 10  # ClusterState leaf count
 
-def _cluster_kernel(meta_ref, src_ref, dst_ref, deg_in, *refs,
-                    xi, kappa, global_tail, block):
-    state_in = refs[:10]
-    (v2ch, v2ct, volh, volt, ld, nexth, nextt, cnth, cntt, alloch) = refs[10:]
+
+def _cluster_kernel(meta_ref, src_ref, dst_ref, *refs, xi, kappa,
+                    global_tail, block):
+    n = _CLUSTER_LEAVES
+    deg_hbm = refs[0]
+    ins = refs[1:1 + n]
+    outs = refs[1 + n:1 + 2 * n]
+    deg, *state, sem = refs[1 + 2 * n:]
+    (v2ch, v2ct, volh, volt, ld, nexth, nextt, cnth, cntt, alloch) = state
     i = pl.program_id(0)
 
     @pl.when(i == 0)
     def _():
-        for dst_ref_, src_ref_ in zip(
-                (v2ch, v2ct, volh, volt, ld, nexth, nextt, cnth, cntt,
-                 alloch), state_in):
-            dst_ref_[...] = src_ref_[...]
+        _copy(deg_hbm, deg, sem.at[0])
+        for hbm, smem in zip(ins, state):
+            _copy(hbm, smem, sem.at[0])
 
     limit = meta_ref[0]
     sink = volh.shape[0] - 1  # masked-write sink slot (static)
 
     def body(e, _):
         g = i * block + e
-        u = src_ref[g]
-        v = dst_ref[g]
+        u = src_ref[e]
+        v = dst_ref[e]
         real = g < limit
-        du = deg_in[u, 0]
-        dv = deg_in[v, 0]
+        du = deg[u]
+        dv = deg[v]
         is_head = (du > xi) & (dv > xi)
         valid = real & (u != v)
 
         # ---------------- head branch (global-degree volumes) ----------
-        cu = v2ch[u, 0]
-        cv = v2ch[v, 0]
+        cu = v2ch[u]
+        cv = v2ch[v]
         new_u = cu < 0
         new_v = cv < 0
         h_on = is_head & valid
-        nh = nexth[0, 0]
+        nh = nexth[0]
         cu2 = jnp.where(new_u, nh, cu)
         nh = nh + jnp.where(h_on & new_u, 1, 0)
         cv2 = jnp.where(new_v, nh, cv)
         nh = nh + jnp.where(h_on & new_v, 1, 0)
-        nexth[0, 0] = nh
+        nexth[0] = nh
         idx = jnp.where(h_on & new_u, cu2, sink)
-        volh[idx, 0] = volh[idx, 0] + jnp.where(h_on & new_u, du, 0)
+        volh[idx] = volh[idx] + jnp.where(h_on & new_u, du, 0)
         idx = jnp.where(h_on & new_v, cv2, sink)
-        volh[idx, 0] = volh[idx, 0] + jnp.where(h_on & new_v, dv, 0)
-        cnth[u, 0] = cnth[u, 0] + jnp.where(h_on, 1, 0)
-        cnth[v, 0] = cnth[v, 0] + jnp.where(h_on, 1, 0)
-        alloch[u, 0] = alloch[u, 0] + jnp.where(h_on & new_u, du, 0)
-        alloch[v, 0] = alloch[v, 0] + jnp.where(h_on & new_v, dv, 0)
-        v2ch[u, 0] = jnp.where(h_on, cu2, v2ch[u, 0])
-        v2ch[v, 0] = jnp.where(h_on, cv2, v2ch[v, 0])
-        vu = volh[cu2, 0]
-        vv = volh[cv2, 0]
+        volh[idx] = volh[idx] + jnp.where(h_on & new_v, dv, 0)
+        cnth[u] = cnth[u] + jnp.where(h_on, 1, 0)
+        cnth[v] = cnth[v] + jnp.where(h_on, 1, 0)
+        alloch[u] = alloch[u] + jnp.where(h_on & new_u, du, 0)
+        alloch[v] = alloch[v] + jnp.where(h_on & new_v, dv, 0)
+        v2ch[u] = jnp.where(h_on, cu2, v2ch[u])
+        v2ch[v] = jnp.where(h_on, cv2, v2ch[v])
+        vu = volh[cu2]
+        vv = volh[cv2]
         both_small = (vu < kappa) & (vv < kappa) & (cu2 != cv2)
         score_u = vu - du
         score_v = vv - dv
@@ -391,60 +465,73 @@ def _cluster_kernel(meta_ref, src_ref, dst_ref, deg_in, *refs,
         cj = jnp.where(u_is_i, cv2, cu2)
         i_vtx = jnp.where(u_is_i, u, v)
         di = jnp.where(u_is_i, du, dv)
-        can_mig = h_on & both_small & (volh[cj, 0] + di < kappa)
+        can_mig = h_on & both_small & (volh[cj] + di < kappa)
         idx = jnp.where(can_mig, cj, sink)
-        volh[idx, 0] = volh[idx, 0] + jnp.where(can_mig, di, 0)
+        volh[idx] = volh[idx] + jnp.where(can_mig, di, 0)
         idx = jnp.where(can_mig, ci, sink)
-        volh[idx, 0] = volh[idx, 0] + jnp.where(can_mig, -di, 0)
-        v2ch[i_vtx, 0] = jnp.where(can_mig, cj, v2ch[i_vtx, 0])
+        volh[idx] = volh[idx] + jnp.where(can_mig, -di, 0)
+        v2ch[i_vtx] = jnp.where(can_mig, cj, v2ch[i_vtx])
 
         # ---------------- tail branch (local-degree volumes) -----------
         t_on = (~is_head) & valid
-        tu = v2ct[u, 0]
-        tv = v2ct[v, 0]
+        tu = v2ct[u]
+        tv = v2ct[v]
         tnew_u = tu < 0
         tnew_v = tv < 0
-        nt = nextt[0, 0]
+        nt = nextt[0]
         tu2 = jnp.where(tnew_u, nt, tu)
         nt = nt + jnp.where(t_on & tnew_u, 1, 0)
         tv2 = jnp.where(tnew_v, nt, tv)
         nt = nt + jnp.where(t_on & tnew_v, 1, 0)
-        nextt[0, 0] = nt
+        nextt[0] = nt
         if global_tail:
             idx = jnp.where(t_on & tnew_u, tu2, sink)
-            volt[idx, 0] = volt[idx, 0] + jnp.where(t_on & tnew_u, du, 0)
+            volt[idx] = volt[idx] + jnp.where(t_on & tnew_u, du, 0)
             idx = jnp.where(t_on & tnew_v, tv2, sink)
-            volt[idx, 0] = volt[idx, 0] + jnp.where(t_on & tnew_v, dv, 0)
+            volt[idx] = volt[idx] + jnp.where(t_on & tnew_v, dv, 0)
         else:
             idx = jnp.where(t_on, tu2, sink)
-            volt[idx, 0] = volt[idx, 0] + jnp.where(t_on, 1, 0)
+            volt[idx] = volt[idx] + jnp.where(t_on, 1, 0)
             idx = jnp.where(t_on, tv2, sink)
-            volt[idx, 0] = volt[idx, 0] + jnp.where(t_on, 1, 0)
-            ld[u, 0] = ld[u, 0] + jnp.where(t_on, 1, 0)
-            ld[v, 0] = ld[v, 0] + jnp.where(t_on, 1, 0)
-        v2ct[u, 0] = jnp.where(t_on, tu2, v2ct[u, 0])
-        v2ct[v, 0] = jnp.where(t_on, tv2, v2ct[v, 0])
-        cntt[u, 0] = cntt[u, 0] + jnp.where(t_on, 1, 0)
-        cntt[v, 0] = cntt[v, 0] + jnp.where(t_on, 1, 0)
-        tvu = volt[tu2, 0]
-        tvv = volt[tv2, 0]
+            volt[idx] = volt[idx] + jnp.where(t_on, 1, 0)
+            ld[u] = ld[u] + jnp.where(t_on, 1, 0)
+            ld[v] = ld[v] + jnp.where(t_on, 1, 0)
+        v2ct[u] = jnp.where(t_on, tu2, v2ct[u])
+        v2ct[v] = jnp.where(t_on, tv2, v2ct[v])
+        cntt[u] = cntt[u] + jnp.where(t_on, 1, 0)
+        cntt[v] = cntt[v] + jnp.where(t_on, 1, 0)
+        tvu = volt[tu2]
+        tvv = volt[tv2]
         t_small = (tvu < kappa) & (tvv < kappa) & (tu2 != tv2)
         tu_is_i = tvu <= tvv
         tci = jnp.where(tu_is_i, tu2, tv2)
         tcj = jnp.where(tu_is_i, tv2, tu2)
         ti = jnp.where(tu_is_i, u, v)
-        ldi = deg_in[ti, 0] if global_tail else ld[ti, 0]
+        ldi = deg[ti] if global_tail else ld[ti]
         t_mig = t_on & t_small
         if global_tail:
-            t_mig = t_mig & (volt[tcj, 0] + ldi < kappa)
+            t_mig = t_mig & (volt[tcj] + ldi < kappa)
         idx = jnp.where(t_mig, tcj, sink)
-        volt[idx, 0] = volt[idx, 0] + jnp.where(t_mig, ldi, 0)
+        volt[idx] = volt[idx] + jnp.where(t_mig, ldi, 0)
         idx = jnp.where(t_mig, tci, sink)
-        volt[idx, 0] = volt[idx, 0] + jnp.where(t_mig, -ldi, 0)
-        v2ct[ti, 0] = jnp.where(t_mig, tcj, v2ct[ti, 0])
+        volt[idx] = volt[idx] + jnp.where(t_mig, -ldi, 0)
+        v2ct[ti] = jnp.where(t_mig, tcj, v2ct[ti])
         return 0
 
     jax.lax.fori_loop(0, block, body, 0)
+
+    @pl.when(i == pl.num_programs(0) - 1)
+    def _():
+        for smem, hbm in zip(state, outs):
+            _copy(smem, hbm, sem.at[0])
+
+
+def cluster_leaf_shapes(n_vertices: int) -> list[tuple[int]]:
+    """1-D shapes of the ClusterState leaves as the kernel holds them (the
+    two volume arrays carry a sink slot, the id counters are length 1)."""
+    V = n_vertices
+    return [(V,), (V,), (V + 1,), (V + 1,), (V,), (1,), (1,), (V,), (V,),
+            (V,)]
 
 
 @functools.partial(jax.jit,
@@ -453,22 +540,24 @@ def _cluster_kernel(meta_ref, src_ref, dst_ref, deg_in, *refs,
 def _cluster_call(meta, src, dst, degrees, *state, xi, kappa, global_tail,
                   block, interpret):
     V = degrees.shape[0]
-    shapes = [(V, 1), (V, 1), (V + 1, 1), (V + 1, 1), (V, 1), (1, 1),
-              (1, 1), (V, 1), (V, 1), (V, 1)]
-    state_specs = [_const_spec(s) for s in shapes]
+    shapes = cluster_leaf_shapes(V)
+    edge = _edge_spec(block)
     kernel = functools.partial(_cluster_kernel, xi=xi, kappa=kappa,
                                global_tail=global_tail, block=block)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=1,
         grid=(src.shape[0] // block,),
-        in_specs=[_const_spec((V, 1))] + state_specs,
-        out_specs=list(state_specs),
+        in_specs=[edge, edge] + [_ANY_SPEC] * (1 + _CLUSTER_LEAVES),
+        out_specs=[_ANY_SPEC] * _CLUSTER_LEAVES,
+        scratch_shapes=([pltpu.SMEM((V,), jnp.int32)]
+                        + [pltpu.SMEM(s, jnp.int32) for s in shapes]
+                        + [pltpu.SemaphoreType.DMA((1,))]),
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct(s, jnp.int32) for s in shapes],
-        input_output_aliases={4 + i: i for i in range(10)},
+        input_output_aliases={4 + i: i for i in range(_CLUSTER_LEAVES)},
         interpret=interpret,
     )(meta, src, dst, degrees, *state)
 
@@ -490,24 +579,15 @@ def cluster_scan(state, src, dst, degrees, *, xi: int, kappa: int,
         return tuple(state)
     blk, pad, interpret = _resolve(block, E, interpret)
     src, dst, _ = _pad_edges(src, dst, None, pad)
-    (v2c_h, v2c_t, vol_h, vol_t, ld, next_h, next_t, cnt_h, cnt_t,
-     alloc_h) = (jnp.asarray(s, jnp.int32) for s in state)
-    V = ld.shape[0]
+    leaves = [jnp.asarray(s, jnp.int32).reshape(-1) for s in state]
     meta = jnp.stack([jnp.int32(E), jnp.int32(1)])
-    packed = (v2c_h.reshape(V, 1), v2c_t.reshape(V, 1),
-              vol_h.reshape(V + 1, 1), vol_t.reshape(V + 1, 1),
-              ld.reshape(V, 1), next_h.reshape(1, 1), next_t.reshape(1, 1),
-              cnt_h.reshape(V, 1), cnt_t.reshape(V, 1),
-              alloc_h.reshape(V, 1))
     _bump_dispatch()
     out = _cluster_call(meta, src, dst,
-                        jnp.asarray(degrees, jnp.int32).reshape(V, 1),
-                        *packed, xi=int(xi), kappa=int(kappa),
+                        jnp.asarray(degrees, jnp.int32).reshape(-1),
+                        *leaves, xi=int(xi), kappa=int(kappa),
                         global_tail=bool(global_tail), block=blk,
                         interpret=interpret)
-    return (out[0][:, 0], out[1][:, 0], out[2][:, 0], out[3][:, 0],
-            out[4][:, 0], out[5][0, 0], out[6][0, 0], out[7][:, 0],
-            out[8][:, 0], out[9][:, 0])
+    return tuple(o[0] if o.shape == (1,) else o for o in out)
 
 
 # ===================================================================
@@ -526,29 +606,31 @@ def _assign_kernel(meta_ref, src_ref, dst_ref, head_ref, pcu_ref, pcv_ref,
     limit = meta_ref[0]
     sign = meta_ref[1]
     cap = meta_ref[2]
-    iota = jax.lax.broadcasted_iota(jnp.int32, (1, k), 1)[0, :]
+    W = load_ref.shape[1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, W), 1)
+    real_lane = lane < k
 
     def body(e, _):
         g = i * block + e
-        u = src_ref[g]
-        v = dst_ref[g]
+        u = src_ref[e]
+        v = dst_ref[e]
         real = g < limit
         is_ins = sign > 0
-        head = head_ref[g] != 0
-        pcu = pcu_ref[g]
-        pcv = pcv_ref[g]
-        load = load_ref[0, :]
-        lu = jnp.sum(jnp.where(iota == pcu, load, 0))
-        lv = jnp.sum(jnp.where(iota == pcv, load, 0))
+        head = head_ref[e] != 0
+        pcu = pcu_ref[e]
+        pcv = pcv_ref[e]
+        load = load_ref[...]
+        lu = jnp.sum(jnp.where(lane == pcu, load, 0))
+        lv = jnp.sum(jnp.where(lane == pcv, load, 0))
         over_u = lu >= cap
         over_v = lv >= cap
-        room = load < cap
-        any_room = jnp.any(room)
-        first_room = jnp.argmax(room).astype(jnp.int32)
+        room = (load < cap) & real_lane
+        any_room = _any(room)
+        first_room = jnp.min(jnp.where(room, lane, W))
         # integer-equal to the oracle's k-1-argmax(room[::-1]) whenever
         # any_room holds (the only case the value is consumed)
-        last_room = jnp.max(jnp.where(room, iota, -1)).astype(jnp.int32)
-        fallback = jnp.argmin(load).astype(jnp.int32)
+        last_room = jnp.max(jnp.where(room, lane, -1))
+        fallback = _first_min(jnp.where(real_lane, load, _MAX_I32), lane, W)
         overflow_choice = jnp.where(
             any_room, jnp.where(head, first_room, last_room), fallback)
         endpoint_choice = jnp.where(lu > lv, pcv, pcu)
@@ -558,7 +640,7 @@ def _assign_kernel(meta_ref, src_ref, dst_ref, head_ref, pcu_ref, pcv_ref,
         pick = jnp.where(is_ins, part_ins, jnp.maximum(p_ret, 0))
         placed = real & (u != v) & jnp.where(is_ins, True, p_ret >= 0)
         w = jnp.where(placed, sign, 0)
-        load_ref[0, :] = load + jnp.where(iota == pick, w, 0)
+        load_ref[...] = load + jnp.where(lane == pick, w, 0)
         parts_ref[e] = jnp.where(
             is_ins, jnp.where(real & (u != v), part_ins, -1), p_ret)
         return 0
@@ -566,26 +648,25 @@ def _assign_kernel(meta_ref, src_ref, dst_ref, head_ref, pcu_ref, pcv_ref,
     jax.lax.fori_loop(0, block, body, 0)
 
 
-@functools.partial(jax.jit, static_argnames=("block", "interpret"))
-def _assign_call(meta, src, dst, head, pcu, pcv, pin, load, *, block,
+@functools.partial(jax.jit, static_argnames=("k", "block", "interpret"))
+def _assign_call(meta, src, dst, head, pcu, pcv, pin, load, *, k, block,
                  interpret):
     Epad = src.shape[0]
-    k = load.shape[1]
+    W = load.shape[1]
+    edge = _edge_spec(block)
     kernel = functools.partial(_assign_kernel, k=k, block=block)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=6,
+        num_scalar_prefetch=1,
         grid=(Epad // block,),
-        in_specs=[_edge_spec(block), _const_spec((1, k))],
-        out_specs=[_edge_spec(block), _const_spec((1, k))],
+        in_specs=[edge] * 6 + [_const_spec((1, W))],
+        out_specs=[edge, _const_spec((1, W))],
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((Epad,), jnp.int32),
-            jax.ShapeDtypeStruct((1, k), jnp.int32),
-        ],
-        input_output_aliases={6: 0, 7: 1},
+        out_shape=[jax.ShapeDtypeStruct(s, jnp.int32)
+                   for s in ((Epad,), (1, W))],
+        input_output_aliases={7: 1},
         interpret=interpret,
     )(meta, src, dst, head, pcu, pcv, pin, load)
 
@@ -622,8 +703,10 @@ def assign_scan(load, src, dst, is_head_edge, pcu, pcv, *, max_load,
     limit = jnp.asarray(E if sign > 0 else n_valid, jnp.int32)
     meta = jnp.stack([limit, jnp.int32(sign),
                       jnp.asarray(max_load, jnp.int32)])
+    W = table_width(k, "assign")
     _bump_dispatch()
-    parts_out, load2 = _assign_call(meta, src, dst, head, pcu, pcv, pin,
-                                    load.reshape(1, k), block=blk,
-                                    interpret=interpret)
-    return parts_out[:E], load2[0]
+    parts_out, load2 = _assign_call(
+        meta, src, dst, head, pcu, pcv, pin,
+        jnp.pad(load, (0, W - k)).reshape(1, W), k=k, block=blk,
+        interpret=interpret)
+    return parts_out[:E], load2[0, :k]

@@ -2,18 +2,31 @@
 
 ``select_path`` picks, per (state size, chunk size), how a chunk runs:
 
-- **fused** — the whole per-vertex state fits the VMEM budget; one
-  blocked-grid megakernel dispatch per chunk with the state VMEM-resident
-  across grid steps;
-- **tiled** — the replica table (and HDRF partial degrees) would blow the
-  budget; same single dispatch, but the table stays HBM-resident and the
-  kernel gathers/scatters rows manually (``pl.load``/``pl.store``);
-- **oracle** — even the edge-id prefetch doesn't fit (or the consumer has
-  no kernel variant): the jitted ``lax.scan`` reference.
+- **fused** — the per-vertex state fits on chip; one blocked-grid
+  megakernel dispatch per chunk with the state resident across grid steps
+  (the packed scoring table in VMEM, Algorithm 1's scalars in SMEM);
+- **tiled** — the scoring table would blow the VMEM budget; same single
+  dispatch, but the table stays in HBM and the kernel DMAs the two
+  endpoint rows of each edge (scoring only);
+- **oracle** — nothing fits (or the consumer has no kernel variant): the
+  jitted ``lax.scan`` reference.
 
-The budget resolves explicit argument → ``REPRO_VMEM_BUDGET`` env var →
-8 MiB default, and the chosen path is logged once per (consumer, mode,
-path) per process (``reset_path_log`` re-arms it, e.g. for tests).
+The gate counts bytes as the chip lays them out (measured against the
+TPU compiler's own out-of-memory reports for v5e):
+
+- VMEM scratch tiles int32 as (8, 128): a ``(V, W)`` table costs
+  ``roundup(V, 8) · roundup(W, 128) · 4`` bytes;
+- a pipelined ``(1, W)`` block costs ``roundup(W, 128) · 4`` bytes per
+  buffer, two buffers each for the input and the output;
+- SMEM holds 1-D int32 arrays in 1024-word tiles; the blocked per-edge
+  operands take two buffers each, and v5e has 1 MiB of SMEM in all.
+
+The VMEM budget resolves explicit argument → ``REPRO_VMEM_BUDGET`` env var
+→ 8 MiB default, and is also handed to the compiler as the kernel's VMEM
+limit, so a state the gate admits is a state the compiler accepts.  The
+chosen path is logged once per (consumer, mode, path) per process and
+listed by ``paths_taken`` (``reset_path_log`` re-arms both, e.g. for
+tests).
 
 The scoring baselines' :class:`~repro.streaming.carry.PartitionerCarry`
 implementations live here too (``GreedyCarry`` / ``HdrfCarry`` /
@@ -34,10 +47,9 @@ import logging
 import os
 
 import jax
-import jax.numpy as jnp
 
 from ...streaming.carry import COUNTED, REPLICATED, SUM, PartitionerCarry
-from .kernel import scoring_scan
+from .kernel import DEFAULT_BLOCK, LANES, scoring_scan, table_width
 from . import ref as _ref
 
 __all__ = [
@@ -45,10 +57,13 @@ __all__ = [
     "GreedyCarry",
     "GridCarry",
     "HdrfCarry",
+    "SMEM_BYTES",
     "VMEM_BUDGET_ENV",
+    "assign_state_bytes",
     "cluster_state_bytes",
     "kernel_fits",
     "make_chunk_fn",
+    "paths_taken",
     "reset_path_log",
     "scoring_state_bytes",
     "select_path",
@@ -57,6 +72,7 @@ __all__ = [
 
 DEFAULT_VMEM_BUDGET = 8 << 20
 VMEM_BUDGET_ENV = "REPRO_VMEM_BUDGET"
+SMEM_BYTES = 1 << 20  # v5e: the compiler reports "1.00M smem"
 
 _log = logging.getLogger(__name__)
 _logged_paths: set[tuple] = set()
@@ -72,47 +88,91 @@ def vmem_budget(explicit: int | None = None) -> int:
     return DEFAULT_VMEM_BUDGET
 
 
-def scoring_state_bytes(n_vertices: int, k: int, mode: str = "hdrf") -> int:
-    """VMEM-resident state of the fused scoring kernel (int32 bytes)."""
-    pd = n_vertices * 4 if mode == "hdrf" else 0
-    return n_vertices * k * 4 + k * 4 + pd
+def _up(n: int, m: int) -> int:
+    return -(-n // m) * m
 
 
-def cluster_state_bytes(n_vertices: int) -> int:
-    """VMEM-resident state of the fused Algorithm-1 kernel: 8 (V,) leaves,
-    2 (V+1,) volume arrays, the degree table, 2 scalar id counters."""
-    return (11 * n_vertices + 4) * 4
+def _vmem_table(rows: int, width: int) -> int:
+    """VMEM scratch bytes of an int32 (rows, width) array: (8, 128) tiles."""
+    return _up(rows, 8) * _up(width, LANES) * 4
 
 
-def _ids_bytes(chunk_size: int) -> int:
-    return 2 * chunk_size * 4  # scalar-prefetched src + dst
+def _vmem_block(width: int) -> int:
+    """A pipelined (1, width) int32/f32 block: two buffers, in and out."""
+    return 2 * 2 * _up(width, LANES) * 4
+
+
+def _smem(words: int) -> int:
+    """SMEM bytes of a 1-D int32 array: 1024-word tiles."""
+    return _up(max(words, 1), 1024) * 4
+
+
+def _edge_smem(chunk_size: int, operands: int) -> int:
+    """Blocked per-edge SMEM operands (two buffers each) plus ``meta``."""
+    block = min(DEFAULT_BLOCK, max(chunk_size, 1))
+    return operands * 2 * _smem(block) + _smem(3)
+
+
+def scoring_state_bytes(n_vertices: int, k: int, mode: str = "hdrf", *,
+                        tiled: bool = False) -> int:
+    """VMEM the scoring kernel holds: the packed table (fused) or two
+    row buffers (tiled), plus the load row and HDRF's λ."""
+    W = table_width(k, mode)
+    small = _vmem_block(W) + (_vmem_block(1) // 2 if mode == "hdrf" else 0)
+    if tiled:
+        return small + 2 * _vmem_table(1, W)
+    return small + _vmem_table(n_vertices, W)
+
+
+def cluster_state_bytes(n_vertices: int, chunk_size: int = 1 << 16) -> int:
+    """SMEM the Algorithm-1 kernel holds: the degree table, 6 (V,) leaves,
+    2 (V+1,) volume arrays with their sink slot, 2 id counters, and the
+    blocked endpoint ids."""
+    V = n_vertices
+    return (7 * _smem(V) + 2 * _smem(V + 1) + 2 * _smem(1)
+            + _edge_smem(chunk_size, 2))
+
+
+def assign_state_bytes(k: int) -> int:
+    """VMEM the Algorithm-3 kernel holds: the load row."""
+    return _vmem_block(table_width(k, "assign"))
 
 
 def select_path(n_vertices: int, k: int, chunk_size: int, *,
                 mode: str = "hdrf", budget: int | None = None,
-                consumer: str = "stream_scan") -> str:
+                consumer: str = "scoring") -> str:
     """Pick ``"fused" | "tiled" | "oracle"`` for one chunk and log the
     choice once per run."""
     b = vmem_budget(budget)
-    ids = _ids_bytes(chunk_size)
     if consumer == "cluster":
-        state = cluster_state_bytes(n_vertices)
-        path = "fused" if state + ids <= b else "oracle"
+        vmem = 0
+        smem = cluster_state_bytes(n_vertices, chunk_size)
+        path = "fused" if smem <= SMEM_BYTES else "oracle"
+    elif consumer == "assign":
+        vmem = assign_state_bytes(k)
+        smem = _edge_smem(chunk_size, 7)
+        path = "fused" if vmem <= b and smem <= SMEM_BYTES else "oracle"
     else:
-        state = scoring_state_bytes(n_vertices, k, mode)
-        if state + ids <= b:
-            path = "fused"
-        elif ids + k * 4 <= b:
-            path = "tiled"
-        else:
-            path = "oracle"
-    key = (consumer, mode, path)
+        smem = _edge_smem(chunk_size, 4)
+        vmem = scoring_state_bytes(n_vertices, k, mode)
+        path = "fused"
+        if vmem > b:
+            vmem = scoring_state_bytes(n_vertices, k, mode, tiled=True)
+            path = "tiled" if vmem <= b else "oracle"
+    key = (consumer, mode if consumer == "scoring" else "", path)
     if key not in _logged_paths:
         _logged_paths.add(key)
         _log.info(
-            "%s[%s]: %s path (state %.1f KiB + ids %.1f KiB, budget %.1f MiB)",
-            consumer, mode, path, state / 1024, ids / 1024, b / (1 << 20))
+            "%s%s: %s path (VMEM %.1f KiB of %.1f MiB, SMEM %.1f KiB of "
+            "%.1f MiB)", consumer, f"[{key[1]}]" if key[1] else "", path,
+            vmem / 1024, b / (1 << 20), smem / 1024, SMEM_BYTES / (1 << 20))
     return path
+
+
+def paths_taken() -> list[tuple[str, str, str]]:
+    """(consumer, mode, path) of every rung chosen since the last reset
+    (``mode`` is empty for the cluster and assign consumers)."""
+    return sorted(_logged_paths)
 
 
 def reset_path_log() -> None:
@@ -122,9 +182,8 @@ def reset_path_log() -> None:
 
 def kernel_fits(n_vertices: int, k: int, chunk_size: int, *,
                 mode: str = "hdrf", budget: int | None = None) -> bool:
-    """Back-compat gate: does the *fused* path fit the VMEM budget?"""
-    state = scoring_state_bytes(n_vertices, k, mode)
-    return state + _ids_bytes(chunk_size) <= vmem_budget(budget)
+    """Back-compat gate: does the *fused* scoring path fit the budget?"""
+    return scoring_state_bytes(n_vertices, k, mode) <= vmem_budget(budget)
 
 
 # ---------------------------------------------------------------------------
@@ -134,47 +193,53 @@ def kernel_fits(n_vertices: int, k: int, chunk_size: int, *,
 
 def _greedy_kernel_chunk(carry, src, dst, *, budget=None):
     load, rep = carry
+    b = vmem_budget(budget)
     path = select_path(rep.shape[0], rep.shape[1], src.shape[0],
-                       mode="greedy", budget=budget)
+                       mode="greedy", budget=b)
     if path == "oracle":
         return _ref.greedy_chunk(carry, src, dst)
     parts, load2, rep2, _ = scoring_scan(
-        src, dst, load, rep, mode="greedy", tiled=(path == "tiled"))
+        src, dst, load, rep, mode="greedy", tiled=(path == "tiled"),
+        vmem_limit=b)
     return (load2, rep2), parts
 
 
 def _greedy_kernel_retract(carry, src, dst, n_valid, parts, *, budget=None):
     load, rep = carry
+    b = vmem_budget(budget)
     path = select_path(rep.shape[0], rep.shape[1], src.shape[0],
-                       mode="greedy", budget=budget)
+                       mode="greedy", budget=b)
     if path == "oracle":
         return _ref.greedy_retract_chunk(carry, src, dst, n_valid, parts)
     _, load2, rep2, _ = scoring_scan(
         src, dst, load, rep, mode="greedy", sign=-1, parts=parts,
-        n_valid=n_valid, tiled=(path == "tiled"))
+        n_valid=n_valid, tiled=(path == "tiled"), vmem_limit=b)
     return (load2, rep2)
 
 
 def _hdrf_kernel_chunk(carry, src, dst, *, budget=None):
     load, rep, pd, lam, kmask = carry
+    b = vmem_budget(budget)
     path = select_path(rep.shape[0], rep.shape[1], src.shape[0],
-                       mode="hdrf", budget=budget)
+                       mode="hdrf", budget=b)
     if path == "oracle":
         return _ref.hdrf_chunk(carry, src, dst)
     parts, load2, rep2, pd2 = scoring_scan(
-        src, dst, load, rep, pd, lam, mode="hdrf", tiled=(path == "tiled"))
+        src, dst, load, rep, pd, lam, mode="hdrf", tiled=(path == "tiled"),
+        vmem_limit=b)
     return (load2, rep2, pd2, lam, kmask), parts
 
 
 def _hdrf_kernel_retract(carry, src, dst, n_valid, parts, *, budget=None):
     load, rep, pd, lam, kmask = carry
+    b = vmem_budget(budget)
     path = select_path(rep.shape[0], rep.shape[1], src.shape[0],
-                       mode="hdrf", budget=budget)
+                       mode="hdrf", budget=b)
     if path == "oracle":
         return _ref.hdrf_retract_chunk(carry, src, dst, n_valid, parts)
     _, load2, rep2, pd2 = scoring_scan(
         src, dst, load, rep, pd, lam, mode="hdrf", sign=-1, parts=parts,
-        n_valid=n_valid, tiled=(path == "tiled"))
+        n_valid=n_valid, tiled=(path == "tiled"), vmem_limit=b)
     return (load2, rep2, pd2, lam, kmask)
 
 

@@ -140,7 +140,10 @@ def hdrf_chunk(carry, src, dst):
         loadf = load.astype(jnp.float32)
         maxl = jnp.max(jnp.where(kmask, loadf, -jnp.inf))
         minl = jnp.min(jnp.where(kmask, loadf, jnp.inf))
-        bal = (maxl - loadf) / (_HDRF_EPS + maxl - minl)
+        # f32 absorbs ε once loads pass 2**15, so a full tie would divide
+        # 0 by 0; its balance term is 0, as ε makes it in exact arithmetic
+        den = _HDRF_EPS + maxl - minl
+        bal = (maxl - loadf) / jnp.where(den > 0, den, 1.0)
         score = jnp.where(kmask, g_u + g_v + lam * bal, -jnp.inf)
         pick = jnp.argmax(score).astype(jnp.int32)
         valid = u != v

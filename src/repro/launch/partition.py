@@ -76,6 +76,7 @@ from ..core import replication_factor, load_balance, gas_comm_bytes
 from ..core.baselines import PARTITIONERS
 from ..graphs import rmat_graph, powerlaw_graph, toy_graph_fig3
 from ..graphs.generators import community_graph
+from ..runtime.compile_cache import enable_compile_cache
 
 
 def load_graph(spec: str, seed: int = 0):
@@ -256,6 +257,12 @@ def run(graph: str, k: int, partitioner: str = "s5p", seed: int = 0,
         window_edges: int | None = None, window_step: int | None = None,
         resize_k: int | None = None, host_budget: int | None = None,
         hybrid: bool = False, budget_fraction: float = 0.5):
+    """Partition ``graph`` and print one quality row per partitioner.
+
+    The plain flow returns ``[(name, RF, balance, GAS bytes/iter, wall s,
+    parts), ...]``; the window/hybrid/resize/carry flows return their own
+    result objects.
+    """
     for pname, v in (("k", k), ("chunk_size", chunk_size), ("window", window),
                      ("num_streams", num_streams)):
         if v < 1:
@@ -397,7 +404,7 @@ def run(graph: str, k: int, partitioner: str = "s5p", seed: int = 0,
         rf = replication_factor(src, dst, parts, n_vertices=n, k=k)
         bal = load_balance(parts, k=k)
         comm = gas_comm_bytes(src, dst, parts, n_vertices=n, k=k)
-        rows.append((name, rf, bal, comm, dt))
+        rows.append((name, rf, bal, comm, dt, parts))
         # partitioners without a stream= parameter run on the materialized
         # arrays in natural arrival order — flag them so a file:-graph
         # comparison table is honest about which rows paged from disk (and
@@ -741,6 +748,7 @@ def main():
     args = ap.parse_args()
     if args.append and not args.write_shards:
         ap.error("--append only makes sense with --write-shards DIR")
+    enable_compile_cache()
     if args.write_shards:
         write_shards_cli(args.graph, args.write_shards, args.shard_edges,
                          args.seed, append=args.append)

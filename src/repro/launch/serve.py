@@ -23,6 +23,7 @@ import numpy as np
 from ..configs import get_arch
 from ..models import lm as LM
 from ..models import recsys as R
+from ..runtime.compile_cache import enable_compile_cache
 
 
 def serve_lm(arch: str, prompt_len: int = 32, gen_tokens: int = 16,
@@ -155,6 +156,7 @@ def main():
                          "reader) instead of deterministic interleave")
     ap.add_argument("--no-cold-restart", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.graph is not None:
         serve_graph(args.graph, k=args.k, window_edges=args.window,
                     step_edges=args.step_edges, background=args.background,

@@ -85,15 +85,6 @@ from .carry import PartitionerCarry
 from .engine import run_carry
 from .stream import Chunk, EdgeStream
 
-try:  # jax ≥ 0.5 top-level API; older releases ship it under experimental
-    _shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover - version shim
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# pvary (varying-axis annotation) only exists on newer jax; on older
-# shard_map it is unnecessary — replicated operands are implicitly varying
-_pvary = getattr(jax.lax, "pvary", lambda x, axes: x)
-
 __all__ = ["ParallelEdgeStream", "run_parallel", "IngestStats", "LaneStats",
            "last_ingest_stats", "reset_cadence_log"]
 
@@ -122,12 +113,14 @@ ISOLATE_CADENCE = 1 << 30
 
 @dataclasses.dataclass(frozen=True)
 class LaneStats:
-    """One lane's share of a ``run_parallel`` drive."""
+    """One lane's share of a ``run_parallel`` drive.  ``device`` is the id
+    of the device that held the lane's carry (None when not observed)."""
 
     chunks: int
     edges: int
     merge_count: int
     wall_s: float
+    device: int | None = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -526,6 +519,20 @@ def _mask_inactive_step(pc):
     return step
 
 
+def _device_of(tree) -> int:
+    """Id of the (single) device holding a carry's first leaf."""
+    leaf = jax.tree_util.tree_leaves(tree)[0]
+    return min(d.id for d in leaf.devices())
+
+
+def _lane_devices(stacked) -> list[int]:
+    """Device id of each lane's row of a lane-sharded (S, ...) carry."""
+    leaf = jax.tree_util.tree_leaves(stacked)[0]
+    shards = sorted(leaf.addressable_shards,
+                    key=lambda sh: sh.index[0].start or 0)
+    return [sh.device.id for sh in shards]
+
+
 def _resolve_backend(backend, S):
     if backend is not None:
         return backend
@@ -654,6 +661,7 @@ def run_parallel(
     lane_chunks = [0] * S
     lane_edges = [0] * S
     lane_wall = [0.0] * S
+    lane_dev: list[int | None] = [None] * S
 
     if backend == "vmap":
         n_ex = len(extras)
@@ -676,6 +684,7 @@ def run_parallel(
             prev = base
             base = pc.merge_stacked(local, prev)
             ctl.observe(prev, base)
+            lane_dev = [_device_of(local)] * S  # one program, one device
             r0 += sc
     elif backend == "shard_map":
         mesh = mesh if mesh is not None else _streams_mesh(S)
@@ -704,6 +713,7 @@ def run_parallel(
                                           len(extras))
             prev = base
             base, parts_b = fns[R](prev, src_b, dst_b, nv_b, *exs_b)
+            lane_dev = _lane_devices(base)
             base = jax.tree_util.tree_map(lambda x: x[0], base)
             ctl.observe(prev, base)
             if pc.emits_parts:
@@ -745,6 +755,7 @@ def run_parallel(
                     local, ch.src, ch.dst, jnp.int32(ch.n_valid), *ch.extras)
                 if parts is not None:
                     parts_by_chunk[cid] = parts[: ch.n_valid]
+            lane_dev[lane_id] = _device_of(local)
             return local, time.perf_counter() - t0
 
         def save_base(carry_val):
@@ -822,7 +833,8 @@ def run_parallel(
         num_streams=S, shard=shard, backend=backend, super_chunk=super_chunk,
         schedule=tuple(ctl.schedule),
         lanes=tuple(LaneStats(chunks=lane_chunks[s], edges=lane_edges[s],
-                              merge_count=merges, wall_s=lane_wall[s])
+                              merge_count=merges, wall_s=lane_wall[s],
+                              device=lane_dev[s])
                     for s in range(S))))
 
     result = pc.finalize(base)
@@ -940,8 +952,7 @@ def _make_super_step(pc, mesh, axis, R, base, n_ex):
     step = _mask_inactive_step(pc)
 
     def body(base_carry, src, dst, nv, *exs):
-        local = jax.tree_util.tree_map(
-            lambda x: _pvary(x, (axis,)), base_carry)
+        local = base_carry
         parts_rounds = []
         for r in range(R):
             local, parts = step(
@@ -955,9 +966,12 @@ def _make_super_step(pc, mesh, axis, R, base, n_ex):
             return merged, jnp.stack(parts_rounds)[None]
         return merged, jnp.zeros((1, 1, 1), jnp.int32)
 
-    return jax.jit(_shard_map(
+    return jax.jit(jax.shard_map(
         body, mesh=mesh,
         in_specs=(jax.tree_util.tree_map(lambda _: P(), base),
                   lane, lane, lane) + (lane,) * n_ex,
         out_specs=(jax.tree_util.tree_map(lambda _: lane, base), lane),
+        # the megakernels' interpret mode cannot carry varying-axis types
+        # through its grid loop, so the body is not vma-checked
+        check_vma=False,
     ))

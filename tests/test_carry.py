@@ -569,7 +569,8 @@ def _run_subprocess(code: str) -> dict:
     out = subprocess.run(
         [sys.executable, "-c", textwrap.dedent(code)],
         capture_output=True, text=True, timeout=1500,
-        env={"PYTHONPATH": SRC_DIR, "XLA_FLAGS":
+        env={"PYTHONPATH": SRC_DIR, "JAX_PLATFORMS": "cpu",
+             "XLA_FLAGS":
              "--xla_force_host_platform_device_count=8",
              "PATH": "/usr/bin:/bin", "HOME": "/root"},
     )

@@ -1,0 +1,135 @@
+"""Compile-only checks of the stream_scan megakernels for a TPU v5e.
+
+The TPU compiler is installed even where no chip is attached: it compiles
+for a *described* v5e and raises what the chip's compiler would raise
+(tile alignment, VMEM/SMEM overruns, unsupported ops).  Interpret mode
+cannot see any of that.  Each test compiles one kernel at a width on one
+side of a ladder boundary (about a second per compile):
+
+- a state the gate admits compiles on the rung it was given;
+- the next width up steps down a rung, and that rung compiles;
+- forcing the old rung at that width is refused by the compiler, so the
+  gate's byte count is tight, not merely safe.
+
+The topology is described inside a module fixture (never at import), and
+the persistent compilation cache is off around these compiles: a compile
+for a described chip is written to it but cannot be read back without one.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import stream_scan as ss
+from repro.kernels.stream_scan import kernel as K
+
+CHUNK = 1 << 16  # the S5PConfig / partition CLI default chunk
+K_PARTS = 32
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    cc.reset_cache()
+
+
+def _shape(sharding, shape, dtype=jnp.int32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *args, **static):
+    compiled = fn.lower(*args, **static).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def _scoring(sharding, mode, V, tiled, budget):
+    W = K.table_width(K_PARTS, mode)
+    args = [_shape(sharding, (2,))]
+    args += [_shape(sharding, (CHUNK,))] * 3
+    args += [_shape(sharding, (1, W)), _shape(sharding, (V, W))]
+    if mode == "hdrf":
+        args.append(_shape(sharding, (1, 1), jnp.float32))
+    return _compile(K._scoring_call, *args, mode=mode, eps=1e-3, k=K_PARTS,
+                    block=K.DEFAULT_BLOCK, tiled=tiled, vmem_limit=budget,
+                    interpret=False)
+
+
+def _cluster(sharding, V):
+    args = [_shape(sharding, (2,)), _shape(sharding, (CHUNK,)),
+            _shape(sharding, (CHUNK,)), _shape(sharding, (V,))]
+    args += [_shape(sharding, s) for s in K.cluster_leaf_shapes(V)]
+    return _compile(K._cluster_call, *args, xi=16, kappa=1 << 20,
+                    global_tail=False, block=K.DEFAULT_BLOCK,
+                    interpret=False)
+
+
+def _last_true(pred, hi):
+    """Largest V in [1, hi] with pred(V), for a monotone pred."""
+    lo = 1
+    assert pred(lo) and not pred(hi)
+    while hi - lo > 1:
+        mid = (lo + hi) // 2
+        lo, hi = (mid, hi) if pred(mid) else (lo, mid)
+    return lo
+
+
+@pytest.mark.parametrize("mode", ["greedy", "hdrf"])
+def test_scoring_fused_tiled_boundary_compiles(one_chip, mode):
+    budget = ss.DEFAULT_VMEM_BUDGET
+    path = functools.partial(ss.select_path, k=K_PARTS, chunk_size=CHUNK,
+                             mode=mode, budget=budget)
+    v_max = _last_true(lambda V: path(V) == "fused", 1 << 20)
+    _scoring(one_chip, mode, v_max, tiled=False, budget=budget)
+    assert path(v_max + 1) == "tiled"
+    _scoring(one_chip, mode, v_max + 1, tiled=True, budget=budget)
+    with pytest.raises(Exception, match="vmem"):
+        _scoring(one_chip, mode, v_max + 1, tiled=False, budget=budget)
+
+
+@pytest.mark.parametrize("mode", ["greedy", "hdrf"])
+def test_scoring_tiled_oracle_boundary_compiles(one_chip, mode):
+    V = 1 << 20  # rmat:20's vertex count: only the tiled rung holds it
+    tiled = ss.scoring_state_bytes(V, K_PARTS, mode, tiled=True)
+    assert ss.select_path(V, K_PARTS, CHUNK, mode=mode,
+                          budget=tiled) == "tiled"
+    _scoring(one_chip, mode, V, tiled=True, budget=tiled)
+    assert ss.select_path(V, K_PARTS, CHUNK, mode=mode,
+                          budget=tiled - 1) == "oracle"
+
+
+def test_cluster_boundary_compiles(one_chip):
+    path = functools.partial(ss.select_path, k=1, chunk_size=CHUNK,
+                             consumer="cluster")
+    v_max = _last_true(lambda V: path(V) == "fused", 1 << 20)
+    _cluster(one_chip, v_max)
+    assert path(v_max + 1) == "oracle"
+    with pytest.raises(Exception, match="smem"):
+        _cluster(one_chip, v_max + 1)
+
+
+@pytest.mark.parametrize("k", [8, K_PARTS])
+def test_assign_compiles_at_default_chunk(one_chip, k):
+    assert ss.select_path(0, k, CHUNK, consumer="assign") == "fused"
+    W = K.table_width(k, "assign")
+    args = [_shape(one_chip, (3,))] + [_shape(one_chip, (CHUNK,))] * 6
+    args.append(_shape(one_chip, (1, W)))
+    _compile(K._assign_call, *args, k=k, block=K.DEFAULT_BLOCK,
+             interpret=False)

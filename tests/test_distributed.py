@@ -22,7 +22,8 @@ def _run(code: str) -> dict:
     out = subprocess.run(
         [sys.executable, "-c", prog],
         capture_output=True, text=True, timeout=900,
-        env={"PYTHONPATH": SRC, "XLA_FLAGS":
+        env={"PYTHONPATH": SRC, "JAX_PLATFORMS": "cpu",
+             "XLA_FLAGS":
              "--xla_force_host_platform_device_count=8", "PATH": "/usr/bin:/bin",
              "HOME": "/root"},
     )

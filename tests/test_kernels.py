@@ -175,6 +175,24 @@ def test_scoring_retract_is_bitwise_inverse(seed, mode, tiled):
     _tree_bitwise(back, carry0, label)
 
 
+@pytest.mark.parametrize("tiled", [False, True], ids=["fused", "tiled"])
+def test_hdrf_scan_parity_once_loads_absorb_eps(tiled):
+    """Past 2**15 edges per partition f32 absorbs HDRF's ε, so a full load
+    tie would score 0/0 = NaN on every lane (and XLA's argmax over NaNs
+    differs between CPU and TPU).  Oracle and kernel both give a tie a zero
+    balance term, as ε does in exact arithmetic: a tie picks the same
+    partition at any load."""
+    src, dst, n, label = _graph(0)
+    carry0 = ss.hdrf_init(n, K, 1.1)
+    carry = (jnp.full((K,), 40_000, jnp.int32),) + tuple(carry0[1:])
+    ref_carry, ref_parts = ss.hdrf_chunk(carry, src, dst)
+    _, low_parts = ss.hdrf_chunk(carry0, src, dst)
+    assert int(ref_parts[0]) == int(low_parts[0])
+    out_carry, parts = _scoring_step_kernel("hdrf", carry, src, dst, tiled)
+    assert np.array_equal(np.asarray(parts), np.asarray(ref_parts)), label
+    _tree_bitwise(out_carry, ref_carry, label)
+
+
 @pytest.mark.parametrize("mode", ["greedy", "hdrf"])
 def test_carry_retract_kernel_matches_oracle(mode):
     """GreedyCarry/HdrfCarry retract through the kernel == the vectorized
@@ -325,24 +343,39 @@ def test_vmem_budget_resolution(monkeypatch):
 def test_select_path_gate_boundaries():
     V, k, chunk = 100, 4, 64
     state = ss.scoring_state_bytes(V, k, "hdrf")
-    ids = 2 * chunk * 4
+    tiled = ss.scoring_state_bytes(V, k, "hdrf", tiled=True)
+    assert ss.select_path(V, k, chunk, mode="hdrf", budget=state) == "fused"
     assert ss.select_path(V, k, chunk, mode="hdrf",
-                          budget=state + ids) == "fused"
+                          budget=state - 1) == "tiled"
+    assert ss.select_path(V, k, chunk, mode="hdrf", budget=tiled) == "tiled"
     assert ss.select_path(V, k, chunk, mode="hdrf",
-                          budget=state + ids - 1) == "tiled"
-    assert ss.select_path(V, k, chunk, mode="hdrf",
-                          budget=ids + k * 4 - 1) == "oracle"
-    assert ss.kernel_fits(V, k, chunk, mode="hdrf", budget=state + ids)
-    assert not ss.kernel_fits(V, k, chunk, mode="hdrf",
-                              budget=state + ids - 1)
-    # greedy state is smaller (no partial degrees): same budget, wider gate
+                          budget=tiled - 1) == "oracle"
+    assert ss.kernel_fits(V, k, chunk, mode="hdrf", budget=state)
+    assert not ss.kernel_fits(V, k, chunk, mode="hdrf", budget=state - 1)
+    # greedy state is smaller (no λ block): same budget, wider gate
     assert ss.scoring_state_bytes(V, k, "greedy") < state
-    # cluster ladder has no tiled rung
-    cstate = ss.cluster_state_bytes(V)
-    assert ss.select_path(V, 1, chunk, consumer="cluster",
-                          budget=cstate + ids) == "fused"
-    assert ss.select_path(V, 1, chunk, consumer="cluster",
-                          budget=cstate + ids - 1) == "oracle"
+    # rows pad to 128 lanes and 8 sublanes: k = 4 costs what k = 127 does,
+    # and HDRF's partial-degree lane spills k = 128 into a second tile
+    assert (ss.scoring_state_bytes(V, 4, "greedy")
+            == ss.scoring_state_bytes(V, 127, "greedy")
+            < ss.scoring_state_bytes(V, 128, "hdrf"))
+    assert (ss.scoring_state_bytes(97, k, "greedy")
+            == ss.scoring_state_bytes(104, k, "greedy"))
+    # the cluster ladder has no tiled rung and is gated by SMEM (1-D
+    # arrays in 1024-word tiles), whatever the VMEM budget
+    for budget in (None, 1):
+        assert ss.select_path(27648, 1, 1 << 16, consumer="cluster",
+                              budget=budget) == "fused"
+        assert ss.select_path(27649, 1, 1 << 16, consumer="cluster",
+                              budget=budget) == "oracle"
+    assert ss.cluster_state_bytes(27648) <= ss.SMEM_BYTES
+    assert ss.cluster_state_bytes(27649) > ss.SMEM_BYTES
+    # Alg. 3 holds one load row: fused unless the budget cannot hold it
+    abytes = ss.assign_state_bytes(k)
+    assert ss.select_path(0, k, 1 << 16, consumer="assign",
+                          budget=abytes) == "fused"
+    assert ss.select_path(0, k, 1 << 16, consumer="assign",
+                          budget=abytes - 1) == "oracle"
 
 
 def test_path_logged_once_per_run(caplog):
@@ -366,8 +399,7 @@ def test_ladder_tiled_path_bitwise_via_carry():
     E = int(src.shape[0])
     if E == 0:
         return
-    state = ss.scoring_state_bytes(n, K, "hdrf")
-    tight = state - 1 + 2 * 65536 * 4  # ids for the default chunk fit
+    tight = ss.scoring_state_bytes(n, K, "hdrf") - 1
     pc_t = ss.HdrfCarry(n, K, use_kernel=True, vmem_budget=tight)
     pc_o = ss.HdrfCarry(n, K, use_kernel=False)
     ca, pa = pc_t.step_chunk(pc_t.init(), src, dst, jnp.int32(E))
