@@ -47,6 +47,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..runtime import spans
 from ..streaming.carry import COUNTED, SUM, PartitionerCarry
 
 __all__ = [
@@ -547,9 +548,9 @@ def compact_clusters(state: ClusterState, degrees: jax.Array, xi: int) -> Cluste
     (membership counter 0) drop out of the id space here.
     """
     eff_h, eff_t = state.effective()
-    v2c_h = np.asarray(eff_h)
-    v2c_t = np.asarray(eff_t)
-    deg = np.asarray(degrees)
+    v2c_h = spans.to_host(eff_h)
+    v2c_t = spans.to_host(eff_t)
+    deg = spans.to_host(degrees)
 
     used_h = np.unique(v2c_h[v2c_h >= 0])
     used_t = np.unique(v2c_t[v2c_t >= 0])

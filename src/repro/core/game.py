@@ -33,6 +33,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..runtime import spans
+
 __all__ = [
     "GameInputs",
     "GameResult",
@@ -346,7 +348,7 @@ def run_game(
     when the equilibrium gain exceeds its migration cost.
     """
     if assign0 is None:
-        assign0 = init_assignment(np.asarray(inputs.sizes), inputs.k)
+        assign0 = init_assignment(spans.to_host(inputs.sizes), inputs.k)
     degs = _cluster_degrees(inputs, n_clusters)
     if delta is None:
         delta = compute_delta(inputs.sizes, degs, inputs.k)

@@ -17,7 +17,6 @@ from __future__ import annotations
 
 import dataclasses
 import math
-import time
 from typing import Any
 
 import jax
@@ -29,6 +28,7 @@ from . import game as _game
 from . import postprocess as _post
 from .cms import CMSketch, SketchCarry, cms_query, pair_key, suggest_params
 from .. import streaming as _stream
+from ..runtime import spans
 
 __all__ = ["S5PConfig", "S5POutput", "s5p_partition", "cluster_statistics"]
 
@@ -103,7 +103,6 @@ class S5POutput:
     kappa: int
     max_load: int
     cluster_assignment: np.ndarray  # (C,) cluster → partition
-    timings: dict[str, float]
     aux: dict[str, Any]
 
 
@@ -177,8 +176,8 @@ def cluster_statistics(
     a_parts, b_parts = [], []
     for a, b, ok in pair_sets:
         ok = ok & (a != b) & (a >= 0) & (b >= 0)
-        a_parts.append(np.asarray(jnp.where(ok, jnp.minimum(a, b), C)))
-        b_parts.append(np.asarray(jnp.where(ok, jnp.maximum(a, b), C)))
+        a_parts.append(spans.to_host(jnp.where(ok, jnp.minimum(a, b), C)))
+        b_parts.append(spans.to_host(jnp.where(ok, jnp.maximum(a, b), C)))
     a_np = np.concatenate(a_parts)
     b_np = np.concatenate(b_parts)
     keys = a_np.astype(np.int64) * (C + 1) + b_np
@@ -220,11 +219,21 @@ def cluster_statistics(
 
 def s5p_partition(src, dst, n_vertices: int, config: S5PConfig,
                   stream: "_stream.EdgeStream | None" = None) -> S5POutput:
+    """One whole S5P job, as the span ``s5p.job``; its phases are the
+    child spans ``s5p.alg1``, ``s5p.compact``, ``s5p.theta``,
+    ``s5p.game``, ``s5p.alg3`` and ``s5p.touch_up``, and every
+    device-to-host pull on the path is a ``host.pull`` span."""
+    with spans.span("s5p.job") as job:
+        out = _s5p_job(src, dst, n_vertices, config, stream)
+        job.wait_for(out.parts)
+    return out
+
+
+def _s5p_job(src, dst, n_vertices, config, stream):
     src = jnp.asarray(src, jnp.int32)
     dst = jnp.asarray(dst, jnp.int32)
     E = int(src.shape[0])
     k = config.k
-    timings: dict[str, float] = {}
 
     # one EdgeStream, replayed by every pass (Fig. 2's single-stream pipeline)
     if stream is None:
@@ -239,71 +248,71 @@ def s5p_partition(src, dst, n_vertices: int, config: S5PConfig,
     kappa = _INT32_MAX if config.bounded else max(int(math.ceil(2.0 * E / k)), 2)
 
     # ---- Phase 1: skewness-aware streaming clustering (Alg. 1) ----
-    t0 = time.perf_counter()
-    state = _cl.cluster_stream(
-        src, dst, n_vertices, xi=xi, kappa=kappa,
-        global_tail=config.bounded, stream=stream,
-        num_streams=config.num_streams, super_chunk=config.super_chunk,
-        shard=config.shard,
-        use_kernel=config.use_kernel, vmem_budget=config.vmem_budget,
-    )
-    res = _cl.compact_clusters(state, degrees, xi)
-    timings["clustering"] = time.perf_counter() - t0
+    with spans.span("s5p.alg1") as sp:
+        state = sp.wait_for(_cl.cluster_stream(
+            src, dst, n_vertices, xi=xi, kappa=kappa,
+            global_tail=config.bounded, stream=stream,
+            num_streams=config.num_streams, super_chunk=config.super_chunk,
+            shard=config.shard,
+            use_kernel=config.use_kernel, vmem_budget=config.vmem_budget,
+        ))
+    with spans.span("s5p.compact") as sp:
+        res = sp.wait_for(_cl.compact_clusters(state, degrees, xi))
 
     if res.n_clusters == 0:  # degenerate: no valid edges
         return S5POutput(
             parts=jnp.full((E,), -1, jnp.int32), k=k, n_clusters=0,
             n_head_clusters=0, game_rounds=0, game_converged=True, xi=xi,
             kappa=kappa, max_load=0, cluster_assignment=np.zeros(0, np.int32),
-            timings=timings, aux={},
+            aux={},
         )
 
     # ---- Phase 2: Stackelberg game (Alg. 2) ----
-    t0 = time.perf_counter()
-    sizes, pa, pb, pw, stats = cluster_statistics(
-        src, dst, res, degrees, xi,
-        use_cms=config.use_cms, cms_epsilon=config.cms_epsilon,
-        cms_nu=config.cms_nu, seed=config.seed,
-        num_streams=config.num_streams, super_chunk=config.super_chunk,
-    )
+    with spans.span("s5p.theta") as sp:
+        sizes, pa, pb, pw, stats = cluster_statistics(
+            src, dst, res, degrees, xi,
+            use_cms=config.use_cms, cms_epsilon=config.cms_epsilon,
+            cms_nu=config.cms_nu, seed=config.seed,
+            num_streams=config.num_streams, super_chunk=config.super_chunk,
+        )
+        sp.wait_for((sizes, pa, pb, pw))
     n_head = res.n_clusters if config.one_stage else res.n_head
     inputs = _game.GameInputs(
         sizes=sizes.astype(jnp.float32), pair_a=pa, pair_b=pb,
         pair_w=pw.astype(jnp.float32), n_head=n_head, k=k,
     )
     bs = _game.default_batch_size(config.game_batch_size, res.n_clusters)
-    game = _game.run_game(
-        inputs, res.n_clusters,
-        batch_size=bs, max_rounds=config.game_max_rounds,
-        accept_prob=config.game_accept_prob, seed=config.seed,
-    )
-    timings["game"] = time.perf_counter() - t0
+    with spans.span("s5p.game") as sp:
+        game = _game.run_game(
+            inputs, res.n_clusters,
+            batch_size=bs, max_rounds=config.game_max_rounds,
+            accept_prob=config.game_accept_prob, seed=config.seed,
+        )
+        sp.wait_for(game.assignment)
 
     # ---- Phase 3: postprocess (Alg. 3) ----
-    t0 = time.perf_counter()
     max_load = _INT32_MAX if config.bounded else int(math.ceil(config.tau * E / k))
-    cu, cv, is_head = _edge_clusters(src, dst, res, degrees, xi)
-    parts, load = _post.assign_edges_stream(
-        src, dst, is_head, jnp.maximum(cu, 0), jnp.maximum(cv, 0),
-        game.assignment, k, max_load, stream=stream,
-        num_streams=config.num_streams, super_chunk=config.super_chunk,
-        shard=config.shard,
-        use_kernel=config.use_kernel, vmem_budget=config.vmem_budget,
-    )
-    timings["postprocess"] = time.perf_counter() - t0
+    with spans.span("s5p.alg3") as sp:
+        cu, cv, is_head = _edge_clusters(src, dst, res, degrees, xi)
+        parts, load = sp.wait_for(_post.assign_edges_stream(
+            src, dst, is_head, jnp.maximum(cu, 0), jnp.maximum(cv, 0),
+            game.assignment, k, max_load, stream=stream,
+            num_streams=config.num_streams, super_chunk=config.super_chunk,
+            shard=config.shard,
+            use_kernel=config.use_kernel, vmem_budget=config.vmem_budget,
+        ))
     ingest = _stream.last_ingest_stats()  # the placement pass's drive
     if ingest is not None:
         stats["parallel_ingest"] = ingest.as_dict()
 
     # ---- post-ingest touch-up (parallel quality recovery) ----
-    c2p = np.asarray(game.assignment)
+    c2p = spans.to_host(game.assignment)
     if (config.num_streams > 1 and config.touch_up
             and config.refine_rounds > 0 and res.n_clusters > 1):
-        t0 = time.perf_counter()
-        parts, load, c2p, tu_stats = _touch_up(
-            src, dst, n_vertices, config, stream, res, inputs, bs,
-            cu, cv, is_head, sizes, parts, load, c2p, k, max_load)
-        timings["touch_up"] = time.perf_counter() - t0
+        with spans.span("s5p.touch_up") as sp:
+            parts, load, c2p, tu_stats = sp.wait_for(_touch_up(
+                src, dst, n_vertices, config, stream, res, inputs, bs,
+                cu, cv, is_head, sizes, parts, load, c2p, k, max_load))
         stats["touch_up"] = tu_stats
 
     # pipeline internals for warm starts (repro.incremental builds its
@@ -331,7 +340,6 @@ def s5p_partition(src, dst, n_vertices: int, config: S5PConfig,
         kappa=kappa,
         max_load=max_load,
         cluster_assignment=c2p,
-        timings=timings,
         aux=stats,
     )
 
@@ -351,9 +359,9 @@ def _touch_up(src, dst, n_vertices, config, stream, res, inputs, bs,
     ps = _stream.ParallelEdgeStream(stream, config.num_streams,
                                     shard=config.shard)
     lanes = ps.edge_lanes()
-    cu_np = np.asarray(cu)
-    cv_np = np.asarray(cv)
-    valid = np.asarray(src != dst)
+    cu_np = spans.to_host(cu)
+    cv_np = spans.to_host(cv)
+    valid = spans.to_host(src != dst)
     c_all = np.concatenate([cu_np[valid], cv_np[valid]])
     l_all = np.concatenate([lanes[valid], lanes[valid]])
     ok = c_all >= 0
@@ -362,7 +370,7 @@ def _touch_up(src, dst, n_vertices, config, stream, res, inputs, bs,
     np.minimum.at(mn, c_all[ok], l_all[ok])
     np.maximum.at(mx, c_all[ok], l_all[ok])
     contested = (mx > mn)  # touched by ≥ 2 lanes
-    move_mask = contested & (np.asarray(sizes) > 0)
+    move_mask = contested & (spans.to_host(sizes) > 0)
     stats = {"contested_clusters": int(contested.sum()), "moved_clusters": 0,
              "replayed_edges": 0, "rounds": 0}
     if not move_mask.any():
@@ -375,7 +383,7 @@ def _touch_up(src, dst, n_vertices, config, stream, res, inputs, bs,
         move_mask=move_mask,
     )
     stats["rounds"] = int(refined.rounds)
-    c2p_new = np.asarray(refined.assignment)
+    c2p_new = spans.to_host(refined.assignment)
     moved = np.flatnonzero(c2p_new != c2p)
     stats["moved_clusters"] = int(moved.size)
     if not moved.size:
@@ -386,20 +394,20 @@ def _touch_up(src, dst, n_vertices, config, stream, res, inputs, bs,
                    | moved_mask[np.maximum(cv_np, 0)])
     aidx = np.flatnonzero(aff)
     stats["replayed_edges"] = int(aidx.size)
-    parts_np = np.asarray(parts).copy()
-    load64 = np.asarray(load).astype(np.int64)
+    parts_np = spans.to_host(parts).copy()
+    load64 = spans.to_host(load).astype(np.int64)
     np.subtract.at(load64, parts_np[aidx], 1)
     re_stream = _stream.EdgeStream(
-        np.asarray(src)[aidx], np.asarray(dst)[aidx], n_vertices,
+        spans.to_host(src)[aidx], spans.to_host(dst)[aidx], n_vertices,
         chunk_size=config.chunk_size)
     ac = _post.AssignCarry(k, max_load, jnp.asarray(c2p_new),
                            use_kernel=config.use_kernel,
                            vmem_budget=config.vmem_budget)
     re_parts, load = _stream.run_carry(
         re_stream, ac,
-        jnp.asarray(np.asarray(is_head)[aidx]),
+        jnp.asarray(spans.to_host(is_head)[aidx]),
         jnp.asarray(np.maximum(cu_np[aidx], 0)),
         jnp.asarray(np.maximum(cv_np[aidx], 0)),
         carry=jnp.asarray(load64.astype(np.int32)))
-    parts_np[aidx] = np.asarray(re_parts)
+    parts_np[aidx] = spans.to_host(re_parts)
     return jnp.asarray(parts_np), load, c2p_new, stats

@@ -55,6 +55,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...runtime import spans
+
 __all__ = [
     "DEFAULT_BLOCK",
     "LANES",
@@ -73,24 +75,15 @@ LANES = 128
 _INF_I32 = 2**30  # python int: jnp constants may not be captured by kernels
 _MAX_I32 = 2**31 - 1
 
-# Dispatch accounting: one increment per pallas_call issued.  The bench
-# uses this to demonstrate the 1-dispatch-per-chunk contract (the oracle
-# re-materializes the carry per edge inside its scan).
-_DISPATCHES = 0
+_DISPATCHES = "stream_scan.dispatches"  # one per pallas_call issued
 
 
 def dispatch_count() -> int:
-    return _DISPATCHES
+    return spans.counters().get(_DISPATCHES, 0)
 
 
 def reset_dispatch_count() -> None:
-    global _DISPATCHES
-    _DISPATCHES = 0
-
-
-def _bump_dispatch() -> None:
-    global _DISPATCHES
-    _DISPATCHES += 1
+    spans.reset(_DISPATCHES)
 
 
 def table_width(k: int, mode: str) -> int:
@@ -327,6 +320,7 @@ def _scoring_call(meta, src, dst, pin, load, table, *lam, mode, eps, k,
         input_output_aliases={4: 1, 5: 2},
         compiler_params=_compiler_params(vmem_limit),
         interpret=interpret,
+        name="scoring",
     )(meta, src, dst, pin, load, table, *lam)
 
 
@@ -357,7 +351,8 @@ def scoring_scan(src, dst, load, rep, pd=None, lam=None, *, mode: str,
     rep = jnp.asarray(rep, jnp.int32)
     E = src.shape[0]
     V, k = rep.shape
-    if mode == "hdrf":
+    hdrf = mode == "hdrf"
+    if hdrf:
         pd = jnp.asarray(pd, jnp.int32)
     if E == 0:
         return jnp.zeros((0,), jnp.int32), load, rep, pd
@@ -365,19 +360,38 @@ def scoring_scan(src, dst, load, rep, pd=None, lam=None, *, mode: str,
     src, dst, pin = _pad_edges(src, dst, parts, pad)
     limit = jnp.asarray(E if sign > 0 else n_valid, jnp.int32)
     meta = jnp.stack([limit, jnp.int32(sign)])
-    W = table_width(k, mode)
-    table = jnp.pad(rep, ((0, 0), (0, W - k)))
+    load_w, table = scoring_pack(load, rep, pd if hdrf else None,
+                                 width=table_width(k, mode))
     lam_arg = ()
-    if mode == "hdrf":
-        table = table.at[:, k].set(pd)
+    if hdrf:
         lam_arg = (jnp.asarray(lam, jnp.float32).reshape(1, 1),)
-    _bump_dispatch()
+    spans.count(_DISPATCHES)
     parts_out, load2, table2 = _scoring_call(
-        meta, src, dst, pin, jnp.pad(load, (0, W - k)).reshape(1, W), table,
+        meta, src, dst, pin, load_w, table,
         *lam_arg, mode=mode, eps=float(eps), k=k, block=blk,
         tiled=bool(tiled), vmem_limit=vmem_limit, interpret=interpret)
-    pd2 = table2[:, k] if mode == "hdrf" else None
-    return parts_out[:E], load2[0, :k], table2[:, :k], pd2
+    return scoring_unpack(parts_out, load2, table2, n=E, k=k, hdrf=hdrf)
+
+
+@functools.partial(jax.jit, static_argnames=("width",))
+def scoring_pack(load, rep, pd, *, width):
+    """The scoring kernel's operands from the (k,) load, the (V, k)
+    counted table and HDRF's (V,) partial degrees (``None`` for greedy):
+    the load as one ``(1, width)`` row and the packed ``(V, width)`` table,
+    its lane ``k`` holding ``pd``."""
+    k = rep.shape[1]
+    table = jnp.pad(rep, ((0, 0), (0, width - k)))
+    if pd is not None:
+        table = jax.lax.dynamic_update_slice(table, pd[:, None], (0, k))
+    return jnp.pad(load, (0, width - k)).reshape(1, width), table
+
+
+@functools.partial(jax.jit, static_argnames=("n", "k", "hdrf"))
+def scoring_unpack(parts, load, table, *, n, k, hdrf):
+    """The inverse of :func:`scoring_pack` on the kernel's outputs:
+    ``(parts[:n], load (k,), table (V, k), pd (V,) or None)``."""
+    pd = table[:, k] if hdrf else None
+    return parts[:n], load[0, :k], table[:, :k], pd
 
 
 def stream_scan_tpu(src, dst, load, rep, pd, lam, *, mode: str,
@@ -559,6 +573,7 @@ def _cluster_call(meta, src, dst, degrees, *state, xi, kappa, global_tail,
         out_shape=[jax.ShapeDtypeStruct(s, jnp.int32) for s in shapes],
         input_output_aliases={4 + i: i for i in range(_CLUSTER_LEAVES)},
         interpret=interpret,
+        name="cluster",
     )(meta, src, dst, degrees, *state)
 
 
@@ -581,7 +596,7 @@ def cluster_scan(state, src, dst, degrees, *, xi: int, kappa: int,
     src, dst, _ = _pad_edges(src, dst, None, pad)
     leaves = [jnp.asarray(s, jnp.int32).reshape(-1) for s in state]
     meta = jnp.stack([jnp.int32(E), jnp.int32(1)])
-    _bump_dispatch()
+    spans.count(_DISPATCHES)
     out = _cluster_call(meta, src, dst,
                         jnp.asarray(degrees, jnp.int32).reshape(-1),
                         *leaves, xi=int(xi), kappa=int(kappa),
@@ -668,6 +683,7 @@ def _assign_call(meta, src, dst, head, pcu, pcv, pin, load, *, k, block,
                    for s in ((Epad,), (1, W))],
         input_output_aliases={7: 1},
         interpret=interpret,
+        name="assign",
     )(meta, src, dst, head, pcu, pcv, pin, load)
 
 
@@ -704,7 +720,7 @@ def assign_scan(load, src, dst, is_head_edge, pcu, pcv, *, max_load,
     meta = jnp.stack([limit, jnp.int32(sign),
                       jnp.asarray(max_load, jnp.int32)])
     W = table_width(k, "assign")
-    _bump_dispatch()
+    spans.count(_DISPATCHES)
     parts_out, load2 = _assign_call(
         meta, src, dst, head, pcu, pcv, pin,
         jnp.pad(load, (0, W - k)).reshape(1, W), k=k, block=blk,

@@ -27,6 +27,8 @@ from typing import Iterator, NamedTuple, Sequence
 import jax.numpy as jnp
 import numpy as np
 
+from ..runtime import spans
+
 __all__ = ["Chunk", "EdgeStream", "ORDERINGS"]
 
 ORDERINGS = ("natural", "shuffled", "dst-sorted", "windowed")
@@ -92,8 +94,8 @@ class EdgeStream:
             raise ValueError("chunk_size must be >= 1")
         if window < 1:
             raise ValueError("window must be >= 1")
-        self.src = np.asarray(src, np.int32)
-        self.dst = np.asarray(dst, np.int32)
+        self.src = np.asarray(spans.to_host(src), np.int32)
+        self.dst = np.asarray(spans.to_host(dst), np.int32)
         if self.src.shape != self.dst.shape:
             raise ValueError("src/dst shape mismatch")
         if n_vertices is None:  # metadata only — infer when not supplied
@@ -173,7 +175,8 @@ class EdgeStream:
             s = np.concatenate([s, np.zeros(padn, np.int32)])
             d = np.concatenate([d, np.zeros(padn, np.int32)])
             exc = [
-                np.concatenate([e, np.zeros((padn,) + e.shape[1:], e.dtype)])
+                np.concatenate([spans.to_host(e),
+                                np.zeros((padn,) + e.shape[1:], e.dtype)])
                 for e in exc
             ]
         return Chunk(
