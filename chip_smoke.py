@@ -5,7 +5,8 @@
 
 One chip: the three ``stream_scan`` megakernels (scoring greedy/HDRF
 insert + retract, Alg. 1, Alg. 3) against their ``lax.scan`` oracles at a
-width the fused rung admits; S5P and HDRF partitioning ``rmat:18`` (cut
+width the fused rung admits, and Alg. 1 again at V = 65,536 on its tiled
+rung; S5P and HDRF partitioning ``rmat:18`` (cut
 from ``rmat:20``, see ``SCALE``) at k = 32 through
 ``repro.launch.partition.run`` (the automatic kernel path) against the
 same partitioner with ``use_kernel=False``, bitwise; and a short
@@ -54,13 +55,15 @@ from repro.runtime.compile_cache import enable_compile_cache  # noqa: E402
 from repro.streaming import (EdgeStream, last_ingest_stats,  # noqa: E402
                              run_parallel)
 FULL_SCALE = 20  # rmat:20 with edge_factor 8: V = 1,048,576, E = 8,042,892
-# Alg. 1 runs its lax.scan rung at these V, twice per smoke, at about 40 µs
-# per edge on one v5e: rmat:20 would take about 1,000 s of the 1,200 s a
-# smoke may run, rmat:19 about 800 s with a warm compile cache.  The
-# default is cut to rmat:18 (V = 262,144, E = 1,969,463, about 330 s) so a
-# cold start keeps a wide margin; pass --scale for the larger graphs.
+# Alg. 1 runs its lax.scan rung at these V (past the tiled rung's 254,976
+# vertices at the default budget), twice per smoke, at about 40 µs per edge
+# on one v5e: rmat:20 would take about 1,000 s of the 1,200 s a smoke may
+# run, rmat:19 about 800 s with a warm compile cache.  The default is cut
+# to rmat:18 (V = 262,144, E = 1,969,463, about 330 s) so a cold start
+# keeps a wide margin; pass --scale for the larger graphs.
 SCALE = 18
 K = 32
+V_TILED = 1 << 16  # the S5P benchmark cell's V: Alg. 1's tiled rung
 
 
 class SmokeFailure(RuntimeError):
@@ -109,7 +112,8 @@ def _kernel_inputs(V: int, E: int, seed: int = 0):
 def phase_kernels(V: int = 16_000, E: int = 1 << 16) -> None:
     """Each megakernel compiled on the chip against its oracle on the chip,
     at a width the fused rung admits (V = 16,000 at k = 32 needs 7.8 MiB of
-    the 8 MiB default VMEM budget)."""
+    the 8 MiB default VMEM budget); Alg. 1 also at ``V_TILED``, on its
+    tiled rung."""
     src, dst = _kernel_inputs(V, E)
     n = jnp.int32(E)
     for mode in ("greedy", "hdrf"):
@@ -139,24 +143,8 @@ def phase_kernels(V: int = 16_000, E: int = 1 << 16) -> None:
         _check(ins_ok, f"scoring[{mode}] insert differs from its oracle")
         _check(ret_ok, f"scoring[{mode}] retract is not the exact inverse")
 
-    deg = compute_degrees(src, dst, V)
-    xi = max(int(np.asarray(deg).mean()), 1)
-    kappa = max(2 * E // K, 2)
-    kern = ClusterCarry(deg, V, xi=xi, kappa=kappa, use_kernel=True)
-    orac = ClusterCarry(deg, V, xi=xi, kappa=kappa, use_kernel=False)
-    ss.reset_path_log()
-    s0 = init_state(V)
-    _ready(kern.step_chunk(s0, src, dst, n))
-    t0 = time.perf_counter()
-    s_k, _ = _ready(kern.step_chunk(s0, src, dst, n))
-    t_k = time.perf_counter() - t0
-    _check(ss.paths_taken() == [("cluster", "", "fused")],
-           f"cluster took {ss.paths_taken()}, expected fused")
-    s_o, _ = _ready(orac.step_chunk(s0, src, dst, n))
-    ok = _same(s_k, s_o)
-    _say("kernel:cluster", V=V, E=E, xi=xi, kappa=kappa, rung=_rungs(),
-         parity=ok, kernel_s=f"{t_k:.4f}")
-    _check(ok, "cluster_scan differs from its oracle")
+    for v_alg1, rung in ((V, "fused"), (V_TILED, "tiled")):
+        phase_alg1(v_alg1, E, rung)
 
     rng = np.random.default_rng(1)
     n_cl = 512
@@ -184,6 +172,34 @@ def phase_kernels(V: int = 16_000, E: int = 1 << 16) -> None:
          insert_parity=ok, retract_parity=ret_ok, kernel_s=f"{t_k:.4f}")
     _check(ok, "assign_scan differs from its oracle")
     _check(ret_ok, "assign_scan retract differs from its oracle")
+
+
+def phase_alg1(V: int, E: int, rung: str) -> None:
+    """Alg. 1 through ``ClusterCarry`` on the rung the ladder picks at V,
+    against its oracle on the chip, bitwise, for S5P and S5P-B tails."""
+    src, dst = _kernel_inputs(V, E)
+    n = jnp.int32(E)
+    deg = compute_degrees(src, dst, V)
+    xi = max(int(np.asarray(deg).mean()), 1)
+    kappa = max(2 * E // K, 2)
+    s0 = init_state(V)
+    for global_tail in (False, True):
+        kw = dict(xi=xi, kappa=kappa, global_tail=global_tail)
+        kern = ClusterCarry(deg, V, use_kernel=True, **kw)
+        orac = ClusterCarry(deg, V, use_kernel=False, **kw)
+        ss.reset_path_log()
+        _ready(kern.step_chunk(s0, src, dst, n))
+        t0 = time.perf_counter()
+        s_k, _ = _ready(kern.step_chunk(s0, src, dst, n))
+        t_k = time.perf_counter() - t0
+        _check(ss.paths_taken() == [("cluster", "", rung)],
+               f"cluster took {ss.paths_taken()}, expected {rung}")
+        s_o, _ = _ready(orac.step_chunk(s0, src, dst, n))
+        ok = _same(s_k, s_o)
+        _say("kernel:cluster", V=V, E=E, xi=xi, kappa=kappa,
+             global_tail=global_tail, rung=_rungs(), parity=ok,
+             kernel_s=f"{t_k:.4f}")
+        _check(ok, f"cluster_scan ({rung}) differs from its oracle")
 
 
 def phase_partition(name: str, scale: int, graph) -> None:

@@ -391,23 +391,28 @@ class ClusterCarry(PartitionerCarry):
         return init_state(self.n_vertices)
 
     def step_chunk(self, carry, src, dst, n_valid, *extras):
+        path = "oracle"
         if self._use_kernel:
             # lazy import: core.baselines imports the kernels package at
             # module level, so the reverse edge must stay function-local
             from ..kernels import stream_scan as _scan
 
+            budget = _scan.vmem_budget(self._vmem_budget)
             path = _scan.select_path(
                 self.n_vertices, 1, src.shape[0], consumer="cluster",
-                budget=self._vmem_budget)
-            if path == "fused":
-                leaves = _scan.cluster_scan(
-                    tuple(carry), src, dst, self.degrees, xi=self.xi,
-                    kappa=self.kappa, global_tail=self.global_tail)
-                return ClusterState(*leaves), None
-        return cluster_chunk(
-            carry, src, dst, self.degrees, xi=self.xi, kappa=self.kappa,
-            global_tail=self.global_tail,
-        ), None
+                budget=budget)
+        spans.count(f"stream_scan.cluster.{path}")
+        if path == "oracle":
+            return cluster_chunk(
+                carry, src, dst, self.degrees, xi=self.xi, kappa=self.kappa,
+                global_tail=self.global_tail,
+            ), None
+        leaves = _scan.cluster_scan(
+            tuple(carry), src, dst, self.degrees, xi=self.xi,
+            kappa=self.kappa, global_tail=self.global_tail,
+            vmem=_scan.cluster_vmem_arrays(self.n_vertices, src.shape[0]),
+            vmem_limit=budget)
+        return ClusterState(*leaves), None
 
     def retract_chunk(self, carry, src, dst, n_valid, parts, *extras):
         return cluster_retract_chunk(carry, src, dst, n_valid, self.degrees,

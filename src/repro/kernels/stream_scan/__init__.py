@@ -7,6 +7,7 @@ seed ``lax.scan`` oracles (bit-identical contract).
 """
 
 from .kernel import (  # noqa: F401
+    CLUSTER_ARRAYS,
     DEFAULT_BLOCK,
     LANES,
     assign_scan,
@@ -19,6 +20,7 @@ from .kernel import (  # noqa: F401
     table_width,
 )
 from .ops import (  # noqa: F401
+    CLUSTER_SMEM_ORDER,
     DEFAULT_VMEM_BUDGET,
     SMEM_BYTES,
     VMEM_BUDGET_ENV,
@@ -27,6 +29,7 @@ from .ops import (  # noqa: F401
     HdrfCarry,
     assign_state_bytes,
     cluster_state_bytes,
+    cluster_vmem_arrays,
     kernel_fits,
     make_chunk_fn,
     paths_taken,

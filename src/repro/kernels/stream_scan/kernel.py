@@ -22,7 +22,10 @@ compiler lays it out:
   two ``(1, W)`` VMEM buffers and back.  A row DMA must be 128-lane
   aligned, which is what fixes ``W``;
 - Algorithm 1's state is all scalar and indexed by data, so it lives in
-  SMEM scratch (DMA'd in at step 0, out at the last step);
+  SMEM scratch (DMA'd in at step 0, out at the last step) while it fits
+  there; past that the arrays SMEM cannot hold sit in VMEM as
+  ``(rows, 128)`` scratch, read and written a row at a time through the
+  same per-edge body (``_Leaf``);
 - Algorithm 3 keeps its load vector as a ``(1, W)`` VMEM block;
 - a ``sign`` operand (+1 insert / -1 retract) reuses the same kernel for
   deletion: the counted replica table is an abelian group, so retraction
@@ -36,8 +39,9 @@ store or an SMEM scalar store.  ``ops.py`` owns the fused → tiled →
 oracle ladder and the byte counts that gate it.
 
 Per-edge math mirrors ``ref.py`` (and ``core.clustering`` /
-``core.postprocess``) expression-for-expression, so interpret mode is
-bit-identical to the oracles — asserted by tests/test_kernels.py and the
+``core.postprocess``) expression-for-expression (Algorithm 1 skips the
+oracle's masked no-op updates), so interpret mode is bit-identical to the
+oracles — asserted by tests/test_kernels.py and the
 pinned goldens in tests/test_streaming.py.
 
 Padding contract: wrappers pad the chunk to a multiple of ``block`` with
@@ -58,6 +62,7 @@ from jax.experimental.pallas import tpu as pltpu
 from ...runtime import spans
 
 __all__ = [
+    "CLUSTER_ARRAYS",
     "DEFAULT_BLOCK",
     "LANES",
     "assign_scan",
@@ -415,129 +420,173 @@ def stream_scan_tpu(src, dst, load, rep, pd, lam, *, mode: str,
 # Algorithm 1 clustering fold
 # ===================================================================
 
-_CLUSTER_LEAVES = 10  # ClusterState leaf count
+# The kernel's per-vertex arrays: the degree table, then the ClusterState
+# leaves in their order (``next_h`` and ``next_t`` are one-word id counters).
+CLUSTER_ARRAYS = ("deg", "v2c_h", "v2c_t", "vol_h", "vol_t", "ld", "next_h",
+                  "next_t", "cnt_h", "cnt_t", "alloc_h")
+
+
+class _Leaf:
+    """Element access to one Algorithm-1 array, held in SMEM (a scalar per
+    element) or in VMEM as ``(rows, 128)`` with element ``x`` at row
+    ``x // 128``, lane ``x % 128``.  A VMEM read is a row load, a lane
+    select and a reduce; a VMEM write is a row load, a select and a row
+    store, since Mosaic cannot store a scalar to VMEM.  With ``on`` given,
+    every write is predicated on it."""
+
+    def __init__(self, ref, vmem: bool, on=None):
+        self.ref = ref
+        self.vmem = vmem
+        self.on = on
+
+    def _at(self, x):
+        row = (pl.ds(x >> 7, 1), slice(None))  # 128 lanes a row
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+        return row, lane == (x & (LANES - 1))
+
+    def __getitem__(self, x):
+        if not self.vmem:
+            return self.ref[x]
+        row, hit = self._at(x)
+        return jnp.sum(jnp.where(hit, self.ref[row], 0))
+
+    def __setitem__(self, x, val):
+        if not self.vmem:
+            self.ref[x] = (val if self.on is None
+                           else jnp.where(self.on, val, self.ref[x]))
+            return
+        row, hit = self._at(x)
+        if self.on is not None:
+            hit = hit & self.on
+        self.ref[row] = jnp.where(hit, val, self.ref[row])
+
+    def add(self, x, d):
+        if self.on is not None:
+            d = jnp.where(self.on, d, 0)
+        if not self.vmem:
+            self.ref[x] = self.ref[x] + d
+            return
+        row, hit = self._at(x)
+        self.ref[row] = self.ref[row] + jnp.where(hit, d, 0)
 
 
 def _cluster_kernel(meta_ref, src_ref, dst_ref, *refs, xi, kappa,
-                    global_tail, block):
-    n = _CLUSTER_LEAVES
-    deg_hbm = refs[0]
-    ins = refs[1:1 + n]
-    outs = refs[1 + n:1 + 2 * n]
-    deg, *state, sem = refs[1 + 2 * n:]
-    (v2ch, v2ct, volh, volt, ld, nexth, nextt, cnth, cntt, alloch) = state
+                    global_tail, block, vmem):
+    n = len(CLUSTER_ARRAYS)
+    ins, outs = refs[:n], refs[n:2 * n - 1]
+    held, sem = refs[2 * n - 1:-1], refs[-1]
     i = pl.program_id(0)
 
     @pl.when(i == 0)
     def _():
-        _copy(deg_hbm, deg, sem.at[0])
-        for hbm, smem in zip(ins, state):
-            _copy(hbm, smem, sem.at[0])
+        for hbm, buf in zip(ins, held):
+            _copy(hbm, buf, sem.at[0])
 
+    def arrays(on=None):
+        return [_Leaf(r, name in vmem, on)
+                for name, r in zip(CLUSTER_ARRAYS, held)]
+
+    deg = _Leaf(held[0], "deg" in vmem)
     limit = meta_ref[0]
-    sink = volh.shape[0] - 1  # masked-write sink slot (static)
 
+    # An edge is head or tail and changes only its branch's arrays (the
+    # oracle's masked updates of the other branch change nothing).  With
+    # arrays in VMEM, where a read costs a row load and a reduce, only the
+    # edge's branch runs, under ``pl.when``; with all in SMEM both run
+    # straight through, writes predicated, which is the faster there (TPU
+    # v5e: 0.0106-0.0111 s against 0.0123 for a 65,536-edge chunk).
     def body(e, _):
-        g = i * block + e
         u = src_ref[e]
         v = dst_ref[e]
-        real = g < limit
+        valid = (i * block + e < limit) & (u != v)
         du = deg[u]
         dv = deg[v]
         is_head = (du > xi) & (dv > xi)
-        valid = real & (u != v)
 
-        # ---------------- head branch (global-degree volumes) ----------
-        cu = v2ch[u]
-        cv = v2ch[v]
-        new_u = cu < 0
-        new_v = cv < 0
-        h_on = is_head & valid
-        nh = nexth[0]
-        cu2 = jnp.where(new_u, nh, cu)
-        nh = nh + jnp.where(h_on & new_u, 1, 0)
-        cv2 = jnp.where(new_v, nh, cv)
-        nh = nh + jnp.where(h_on & new_v, 1, 0)
-        nexth[0] = nh
-        idx = jnp.where(h_on & new_u, cu2, sink)
-        volh[idx] = volh[idx] + jnp.where(h_on & new_u, du, 0)
-        idx = jnp.where(h_on & new_v, cv2, sink)
-        volh[idx] = volh[idx] + jnp.where(h_on & new_v, dv, 0)
-        cnth[u] = cnth[u] + jnp.where(h_on, 1, 0)
-        cnth[v] = cnth[v] + jnp.where(h_on, 1, 0)
-        alloch[u] = alloch[u] + jnp.where(h_on & new_u, du, 0)
-        alloch[v] = alloch[v] + jnp.where(h_on & new_v, dv, 0)
-        v2ch[u] = jnp.where(h_on, cu2, v2ch[u])
-        v2ch[v] = jnp.where(h_on, cv2, v2ch[v])
-        vu = volh[cu2]
-        vv = volh[cv2]
-        both_small = (vu < kappa) & (vv < kappa) & (cu2 != cv2)
-        score_u = vu - du
-        score_v = vv - dv
-        u_is_i = score_u <= score_v  # tie → u (matches reference)
-        ci = jnp.where(u_is_i, cu2, cv2)
-        cj = jnp.where(u_is_i, cv2, cu2)
-        i_vtx = jnp.where(u_is_i, u, v)
-        di = jnp.where(u_is_i, du, dv)
-        can_mig = h_on & both_small & (volh[cj] + di < kappa)
-        idx = jnp.where(can_mig, cj, sink)
-        volh[idx] = volh[idx] + jnp.where(can_mig, di, 0)
-        idx = jnp.where(can_mig, ci, sink)
-        volh[idx] = volh[idx] + jnp.where(can_mig, -di, 0)
-        v2ch[i_vtx] = jnp.where(can_mig, cj, v2ch[i_vtx])
+        def head(on):  # global-degree volumes
+            _, v2ch, _, volh, _, _, nexth, _, cnth, _, alloch = arrays(on)
+            cu = v2ch[u]
+            cv = v2ch[v]
+            new_u = cu < 0
+            new_v = cv < 0
+            nh = nexth[0]
+            cu2 = jnp.where(new_u, nh, cu)
+            nh = nh + jnp.where(new_u, 1, 0)
+            cv2 = jnp.where(new_v, nh, cv)
+            nexth[0] = nh + jnp.where(new_v, 1, 0)
+            volh.add(cu2, jnp.where(new_u, du, 0))
+            volh.add(cv2, jnp.where(new_v, dv, 0))
+            cnth.add(u, 1)
+            cnth.add(v, 1)
+            alloch.add(u, jnp.where(new_u, du, 0))
+            alloch.add(v, jnp.where(new_v, dv, 0))
+            v2ch[u] = cu2
+            v2ch[v] = cv2
+            vu = volh[cu2]
+            vv = volh[cv2]
+            u_is_i = vu - du <= vv - dv  # tie → u (matches reference)
+            ci = jnp.where(u_is_i, cu2, cv2)
+            cj = jnp.where(u_is_i, cv2, cu2)
+            di = jnp.where(u_is_i, du, dv)
+            vol_j = jnp.where(u_is_i, vv, vu)  # vol_h[cj], unwritten since
+            mig = ((vu < kappa) & (vv < kappa) & (cu2 != cv2)
+                   & (vol_j + di < kappa))
+            volh.add(cj, jnp.where(mig, di, 0))
+            volh.add(ci, jnp.where(mig, -di, 0))
+            v2ch[jnp.where(u_is_i, u, v)] = jnp.where(mig, cj, ci)
 
-        # ---------------- tail branch (local-degree volumes) -----------
-        t_on = (~is_head) & valid
-        tu = v2ct[u]
-        tv = v2ct[v]
-        tnew_u = tu < 0
-        tnew_v = tv < 0
-        nt = nextt[0]
-        tu2 = jnp.where(tnew_u, nt, tu)
-        nt = nt + jnp.where(t_on & tnew_u, 1, 0)
-        tv2 = jnp.where(tnew_v, nt, tv)
-        nt = nt + jnp.where(t_on & tnew_v, 1, 0)
-        nextt[0] = nt
-        if global_tail:
-            idx = jnp.where(t_on & tnew_u, tu2, sink)
-            volt[idx] = volt[idx] + jnp.where(t_on & tnew_u, du, 0)
-            idx = jnp.where(t_on & tnew_v, tv2, sink)
-            volt[idx] = volt[idx] + jnp.where(t_on & tnew_v, dv, 0)
-        else:
-            idx = jnp.where(t_on, tu2, sink)
-            volt[idx] = volt[idx] + jnp.where(t_on, 1, 0)
-            idx = jnp.where(t_on, tv2, sink)
-            volt[idx] = volt[idx] + jnp.where(t_on, 1, 0)
-            ld[u] = ld[u] + jnp.where(t_on, 1, 0)
-            ld[v] = ld[v] + jnp.where(t_on, 1, 0)
-        v2ct[u] = jnp.where(t_on, tu2, v2ct[u])
-        v2ct[v] = jnp.where(t_on, tv2, v2ct[v])
-        cntt[u] = cntt[u] + jnp.where(t_on, 1, 0)
-        cntt[v] = cntt[v] + jnp.where(t_on, 1, 0)
-        tvu = volt[tu2]
-        tvv = volt[tv2]
-        t_small = (tvu < kappa) & (tvv < kappa) & (tu2 != tv2)
-        tu_is_i = tvu <= tvv
-        tci = jnp.where(tu_is_i, tu2, tv2)
-        tcj = jnp.where(tu_is_i, tv2, tu2)
-        ti = jnp.where(tu_is_i, u, v)
-        ldi = deg[ti] if global_tail else ld[ti]
-        t_mig = t_on & t_small
-        if global_tail:
-            t_mig = t_mig & (volt[tcj] + ldi < kappa)
-        idx = jnp.where(t_mig, tcj, sink)
-        volt[idx] = volt[idx] + jnp.where(t_mig, ldi, 0)
-        idx = jnp.where(t_mig, tci, sink)
-        volt[idx] = volt[idx] + jnp.where(t_mig, -ldi, 0)
-        v2ct[ti] = jnp.where(t_mig, tcj, v2ct[ti])
+        def tail(on):  # local-degree volumes (global ones for S5P-B)
+            _, _, v2ct, _, volt, ld, _, nextt, _, cntt, _ = arrays(on)
+            tu = v2ct[u]
+            tv = v2ct[v]
+            new_u = tu < 0
+            new_v = tv < 0
+            nt = nextt[0]
+            tu2 = jnp.where(new_u, nt, tu)
+            nt = nt + jnp.where(new_u, 1, 0)
+            tv2 = jnp.where(new_v, nt, tv)
+            nextt[0] = nt + jnp.where(new_v, 1, 0)
+            if global_tail:
+                volt.add(tu2, jnp.where(new_u, du, 0))
+                volt.add(tv2, jnp.where(new_v, dv, 0))
+                ld_u, ld_v = du, dv
+            else:
+                volt.add(tu2, 1)
+                volt.add(tv2, 1)
+                ld_u = ld[u] + 1
+                ld[u] = ld_u
+                ld_v = ld[v] + 1
+                ld[v] = ld_v
+            v2ct[u] = tu2
+            v2ct[v] = tv2
+            cntt.add(u, 1)
+            cntt.add(v, 1)
+            tvu = volt[tu2]
+            tvv = volt[tv2]
+            u_is_i = tvu <= tvv
+            ci = jnp.where(u_is_i, tu2, tv2)
+            cj = jnp.where(u_is_i, tv2, tu2)
+            ldi = jnp.where(u_is_i, ld_u, ld_v)
+            mig = (tvu < kappa) & (tvv < kappa) & (tu2 != tv2)
+            if global_tail:
+                mig = mig & (jnp.where(u_is_i, tvv, tvu) + ldi < kappa)
+            volt.add(cj, jnp.where(mig, ldi, 0))
+            volt.add(ci, jnp.where(mig, -ldi, 0))
+            v2ct[jnp.where(u_is_i, u, v)] = jnp.where(mig, cj, ci)
+
+        for on, branch in ((valid & is_head, head), (valid & ~is_head, tail)):
+            if vmem:
+                pl.when(on)(functools.partial(branch, None))
+            else:
+                branch(on)
         return 0
 
     jax.lax.fori_loop(0, block, body, 0)
 
     @pl.when(i == pl.num_programs(0) - 1)
     def _():
-        for smem, hbm in zip(state, outs):
-            _copy(smem, hbm, sem.at[0])
+        for buf, hbm in zip(held[1:], outs):
+            _copy(buf, hbm, sem.at[0])
 
 
 def cluster_leaf_shapes(n_vertices: int) -> list[tuple[int]]:
@@ -550,42 +599,56 @@ def cluster_leaf_shapes(n_vertices: int) -> list[tuple[int]]:
 
 @functools.partial(jax.jit,
                    static_argnames=("xi", "kappa", "global_tail", "block",
-                                    "interpret"))
+                                    "vmem", "vmem_limit", "interpret"))
 def _cluster_call(meta, src, dst, degrees, *state, xi, kappa, global_tail,
-                  block, interpret):
-    V = degrees.shape[0]
-    shapes = cluster_leaf_shapes(V)
+                  block, vmem=(), vmem_limit=None, interpret):
+    arrays = []
+    for name, a in zip(CLUSTER_ARRAYS, (degrees, *state)):
+        if name in vmem:
+            rows = -(-a.shape[0] // (8 * LANES)) * 8  # whole (8, 128) tiles
+            a = jnp.pad(a, (0, rows * LANES - a.shape[0])).reshape(rows, LANES)
+        arrays.append(a)
     edge = _edge_spec(block)
     kernel = functools.partial(_cluster_kernel, xi=xi, kappa=kappa,
-                               global_tail=global_tail, block=block)
+                               global_tail=global_tail, block=block,
+                               vmem=vmem)
+    n = len(arrays)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(src.shape[0] // block,),
-        in_specs=[edge, edge] + [_ANY_SPEC] * (1 + _CLUSTER_LEAVES),
-        out_specs=[_ANY_SPEC] * _CLUSTER_LEAVES,
-        scratch_shapes=([pltpu.SMEM((V,), jnp.int32)]
-                        + [pltpu.SMEM(s, jnp.int32) for s in shapes]
-                        + [pltpu.SemaphoreType.DMA((1,))]),
+        in_specs=[edge, edge] + [_ANY_SPEC] * n,
+        out_specs=[_ANY_SPEC] * (n - 1),
+        scratch_shapes=(
+            [(pltpu.VMEM if name in vmem else pltpu.SMEM)(a.shape, jnp.int32)
+             for name, a in zip(CLUSTER_ARRAYS, arrays)]
+            + [pltpu.SemaphoreType.DMA((1,))]),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct(s, jnp.int32) for s in shapes],
-        input_output_aliases={4 + i: i for i in range(_CLUSTER_LEAVES)},
+        out_shape=[jax.ShapeDtypeStruct(a.shape, jnp.int32)
+                   for a in arrays[1:]],
+        input_output_aliases={4 + i: i for i in range(n - 1)},
+        compiler_params=_compiler_params(vmem_limit),
         interpret=interpret,
         name="cluster",
-    )(meta, src, dst, degrees, *state)
+    )(meta, src, dst, *arrays)
+    return [o.reshape(-1)[:s.shape[0]] for o, s in zip(out, state)]
 
 
 def cluster_scan(state, src, dst, degrees, *, xi: int, kappa: int,
-                 global_tail: bool = False, block: int | None = None,
+                 global_tail: bool = False, vmem: tuple[str, ...] = (),
+                 vmem_limit: int | None = None, block: int | None = None,
                  interpret: bool | None = None):
     """One fused Algorithm-1 chunk (insert path).
 
     ``state`` is the 10-leaf ``ClusterState`` tuple (plain arrays — this
     module cannot import ``core``); returns the updated leaves in the
-    same order.  Per-edge transitions mirror
-    ``core.clustering._edge_step`` expression-for-expression.
+    same order.  ``vmem`` names the arrays of :data:`CLUSTER_ARRAYS` the
+    kernel holds in VMEM instead of SMEM, with ``vmem_limit`` the
+    compiler's VMEM limit.  Per-edge transitions are
+    ``core.clustering._edge_step``'s, without its masked no-op updates,
+    so every leaf comes out bitwise equal.
     """
     src = jnp.asarray(src, jnp.int32)
     dst = jnp.asarray(dst, jnp.int32)
@@ -601,7 +664,8 @@ def cluster_scan(state, src, dst, degrees, *, xi: int, kappa: int,
                         jnp.asarray(degrees, jnp.int32).reshape(-1),
                         *leaves, xi=int(xi), kappa=int(kappa),
                         global_tail=bool(global_tail), block=blk,
-                        interpret=interpret)
+                        vmem=tuple(a for a in CLUSTER_ARRAYS if a in vmem),
+                        vmem_limit=vmem_limit, interpret=interpret)
     return tuple(o[0] if o.shape == (1,) else o for o in out)
 
 

@@ -5,9 +5,11 @@
 - **fused** — the per-vertex state fits on chip; one blocked-grid
   megakernel dispatch per chunk with the state resident across grid steps
   (the packed scoring table in VMEM, Algorithm 1's scalars in SMEM);
-- **tiled** — the scoring table would blow the VMEM budget; same single
-  dispatch, but the table stays in HBM and the kernel DMAs the two
-  endpoint rows of each edge (scoring only);
+- **tiled** — the state is too big for the fused layout; same single
+  dispatch.  Scoring keeps the table in HBM and DMAs the two endpoint rows
+  of each edge; Algorithm 1 keeps in SMEM the arrays of
+  :data:`CLUSTER_SMEM_ORDER` that fit there and holds the rest in VMEM,
+  one ``(ceil(V / 128), 128)`` scratch each;
 - **oracle** — nothing fits (or the consumer has no kernel variant): the
   jitted ``lax.scan`` reference.
 
@@ -20,6 +22,13 @@ TPU compiler's own out-of-memory reports for v5e):
   buffer, two buffers each for the input and the output;
 - SMEM holds 1-D int32 arrays in 1024-word tiles; the blocked per-edge
   operands take two buffers each, and v5e has 1 MiB of SMEM in all.
+
+Byte counts of the cluster consumer at the default 65,536-edge chunk:
+fused, everything in SMEM, up to V = 27,648; tiled, VMEM
+``Σ roundup(ceil(words / 128), 8) · 512`` over the arrays SMEM cannot
+hold, plus 1 KiB the compiler keeps (V = 65,536: six arrays, 1.5 MiB),
+up to V = 254,976 at the 8 MiB budget, where the degree table too leaves
+SMEM; oracle beyond.
 
 The VMEM budget resolves explicit argument → ``REPRO_VMEM_BUDGET`` env var
 → 8 MiB default, and is also handed to the compiler as the kernel's VMEM
@@ -53,6 +62,7 @@ from .kernel import DEFAULT_BLOCK, LANES, scoring_scan, table_width
 from . import ref as _ref
 
 __all__ = [
+    "CLUSTER_SMEM_ORDER",
     "DEFAULT_VMEM_BUDGET",
     "GreedyCarry",
     "GridCarry",
@@ -61,6 +71,7 @@ __all__ = [
     "VMEM_BUDGET_ENV",
     "assign_state_bytes",
     "cluster_state_bytes",
+    "cluster_vmem_arrays",
     "kernel_fits",
     "make_chunk_fn",
     "paths_taken",
@@ -124,11 +135,45 @@ def scoring_state_bytes(n_vertices: int, k: int, mode: str = "hdrf", *,
     return small + _vmem_table(n_vertices, W)
 
 
-def cluster_state_bytes(n_vertices: int, chunk_size: int = 1 << 16) -> int:
-    """SMEM the Algorithm-1 kernel holds: the degree table, 6 (V,) leaves,
-    2 (V+1,) volume arrays with their sink slot, 2 id counters, and the
-    blocked endpoint ids."""
+# Algorithm 1's per-vertex arrays in the order they claim the kernel's
+# SMEM: the degree table and the two volume arrays are read and written most
+# per edge; the membership counters and the allocation record are only
+# added to, which costs no scalar read in VMEM.
+CLUSTER_SMEM_ORDER = ("deg", "vol_h", "vol_t", "v2c_h", "v2c_t", "ld",
+                      "cnt_h", "cnt_t", "alloc_h")
+
+
+def _cluster_words(name: str, n_vertices: int) -> int:
+    return n_vertices + 1 if name.startswith("vol") else n_vertices
+
+
+def cluster_vmem_arrays(n_vertices: int,
+                        chunk_size: int = 1 << 16) -> tuple[str, ...]:
+    """The Algorithm-1 arrays that do not fit in SMEM beside the ones
+    before them in :data:`CLUSTER_SMEM_ORDER`, with the blocked endpoint
+    ids and the two id counters: the kernel holds these in VMEM.  Empty
+    when everything fits SMEM (the fused rung)."""
+    free = SMEM_BYTES - _edge_smem(chunk_size, 2) - 2 * _smem(1)
+    for i, name in enumerate(CLUSTER_SMEM_ORDER):
+        free -= _smem(_cluster_words(name, n_vertices))
+        if free < 0:
+            return CLUSTER_SMEM_ORDER[i:]
+    return ()
+
+
+def cluster_state_bytes(n_vertices: int, chunk_size: int = 1 << 16, *,
+                        tiled: bool = False) -> int:
+    """Fused: the SMEM the Algorithm-1 kernel holds with every array there
+    (the degree table, 6 (V,) leaves, 2 (V+1,) volume arrays with their
+    sink slot, 2 id counters, and the blocked endpoint ids).  Tiled: the
+    VMEM of the arrays :func:`cluster_vmem_arrays` moves there, each
+    ``(ceil(words / 128), 128)``, and the 1 KiB the compiler keeps beside
+    them (the least limit the v5e compiler accepts is exactly that sum)."""
     V = n_vertices
+    if tiled:
+        return 1024 + sum(
+            _vmem_table(-(-_cluster_words(a, V) // LANES), LANES)
+            for a in cluster_vmem_arrays(V, chunk_size))
     return (7 * _smem(V) + 2 * _smem(V + 1) + 2 * _smem(1)
             + _edge_smem(chunk_size, 2))
 
@@ -147,7 +192,12 @@ def select_path(n_vertices: int, k: int, chunk_size: int, *,
     if consumer == "cluster":
         vmem = 0
         smem = cluster_state_bytes(n_vertices, chunk_size)
-        path = "fused" if smem <= SMEM_BYTES else "oracle"
+        path = "fused"
+        if smem > SMEM_BYTES:
+            moved = cluster_vmem_arrays(n_vertices, chunk_size)
+            smem -= sum(_smem(_cluster_words(a, n_vertices)) for a in moved)
+            vmem = cluster_state_bytes(n_vertices, chunk_size, tiled=True)
+            path = "tiled" if vmem <= b else "oracle"
     elif consumer == "assign":
         vmem = assign_state_bytes(k)
         smem = _edge_smem(chunk_size, 7)

@@ -72,13 +72,13 @@ def _scoring(sharding, mode, V, tiled, budget):
                     interpret=False)
 
 
-def _cluster(sharding, V):
+def _cluster(sharding, V, vmem=(), budget=None):
     args = [_shape(sharding, (2,)), _shape(sharding, (CHUNK,)),
             _shape(sharding, (CHUNK,)), _shape(sharding, (V,))]
     args += [_shape(sharding, s) for s in K.cluster_leaf_shapes(V)]
     return _compile(K._cluster_call, *args, xi=16, kappa=1 << 20,
-                    global_tail=False, block=K.DEFAULT_BLOCK,
-                    interpret=False)
+                    global_tail=False, block=K.DEFAULT_BLOCK, vmem=vmem,
+                    vmem_limit=budget, interpret=False)
 
 
 def _last_true(pred, hi):
@@ -116,13 +116,45 @@ def test_scoring_tiled_oracle_boundary_compiles(one_chip, mode):
 
 
 def test_cluster_boundary_compiles(one_chip):
+    budget = ss.DEFAULT_VMEM_BUDGET
     path = functools.partial(ss.select_path, k=1, chunk_size=CHUNK,
-                             consumer="cluster")
+                             consumer="cluster", budget=budget)
     v_max = _last_true(lambda V: path(V) == "fused", 1 << 20)
     _cluster(one_chip, v_max)
-    assert path(v_max + 1) == "oracle"
+    assert path(v_max + 1) == "tiled"
+    _cluster(one_chip, v_max + 1, ss.cluster_vmem_arrays(v_max + 1, CHUNK),
+             budget)
     with pytest.raises(Exception, match="smem"):
         _cluster(one_chip, v_max + 1)
+
+
+def test_cluster_tiled_compiles_at_the_s5p_cell_shape(one_chip):
+    """The S5P cell's V = 65,536 with 65,536-edge chunks takes the tiled
+    rung under the default budget, and the gate's VMEM count is the least
+    limit the compiler accepts."""
+    V = 1 << 16
+    budget = ss.DEFAULT_VMEM_BUDGET
+    assert ss.select_path(V, 1, CHUNK, consumer="cluster",
+                          budget=budget) == "tiled"
+    vmem = ss.cluster_vmem_arrays(V, CHUNK)
+    _cluster(one_chip, V, vmem, budget)
+    need = ss.cluster_state_bytes(V, CHUNK, tiled=True)
+    _cluster(one_chip, V, vmem, need)
+    with pytest.raises(Exception, match="vmem"):
+        _cluster(one_chip, V, vmem, need - 1)
+
+
+def test_cluster_tiled_oracle_boundary_compiles(one_chip):
+    budget = ss.DEFAULT_VMEM_BUDGET
+    path = functools.partial(ss.select_path, k=1, chunk_size=CHUNK,
+                             consumer="cluster", budget=budget)
+    v_max = _last_true(lambda V: path(V) != "oracle", 1 << 20)
+    assert path(v_max) == "tiled"
+    _cluster(one_chip, v_max, ss.cluster_vmem_arrays(v_max, CHUNK), budget)
+    assert path(v_max + 1) == "oracle"
+    with pytest.raises(Exception, match="vmem"):
+        _cluster(one_chip, v_max + 1,
+                 ss.cluster_vmem_arrays(v_max + 1, CHUNK), budget)
 
 
 @pytest.mark.parametrize("k", [8, K_PARTS])

@@ -242,23 +242,40 @@ if HAVE_HYPOTHESIS:
 # --------------------------------------------------- Alg. 1 / Alg. 3 kernels
 
 
+# V just past the SMEM gate: the tiled rung, one array in VMEM
+V_TILED = 28_000
+# each rung's (vertex count or None for the graph's own, arrays in VMEM)
+_CLUSTER_RUNGS = {
+    "fused": (None, lambda V, E: ()),
+    "tiled": (V_TILED, ss.cluster_vmem_arrays),
+    "all-vmem": (None, lambda V, E: ss.CLUSTER_ARRAYS),
+}
+
+
+@pytest.mark.parametrize("rung", list(_CLUSTER_RUNGS))
 @pytest.mark.parametrize("global_tail", [False, True], ids=["s5p", "s5p-b"])
 @pytest.mark.parametrize("seed", list(cases(3)))
-def test_cluster_scan_parity(seed, global_tail):
+def test_cluster_scan_parity(seed, global_tail, rung):
+    """Every leaf bitwise equal to the lax.scan oracle over two chunks (the
+    second starts from assigned vertices), each with a ragged last block."""
     from repro.core.clustering import compute_degrees, init_state
 
     src, dst, n, label = _graph(seed)
     if src.shape[0] == 0:
         return
-    deg = compute_degrees(src, dst, n)
-    xi = max(int(np.asarray(deg).mean()), 1)
+    V, place = _CLUSTER_RUNGS[rung]
+    V = V or n
+    vmem = place(V, int(src.shape[0]))
+    assert bool(vmem) == (rung != "fused")
+    deg = compute_degrees(src, dst, V)
+    xi = max(int(np.asarray(deg[:n]).mean()), 1)
     kappa = max(2 * int(src.shape[0]) // K, 2)
-    s0 = tuple(init_state(n))
-    ref = ss.cluster_chunk_oracle(s0, src, dst, deg, xi=xi, kappa=kappa,
-                                  global_tail=global_tail)
-    out = ss.cluster_scan(s0, src, dst, deg, xi=xi, kappa=kappa,
-                          global_tail=global_tail, block=64)
-    _tree_bitwise(out, ref, label)
+    kw = dict(xi=xi, kappa=kappa, global_tail=global_tail)
+    ref = out = tuple(init_state(V))
+    for s, d in ((src, dst), (dst, src)):
+        ref = ss.cluster_chunk_oracle(ref, s, d, deg, **kw)
+        out = ss.cluster_scan(out, s, d, deg, vmem=vmem, block=64, **kw)
+        _tree_bitwise(out, ref, label)
 
 
 @pytest.mark.parametrize("seed", list(cases(3)))
@@ -292,18 +309,31 @@ def test_assign_scan_parity(seed):
         np.asarray(l2), np.asarray(_retract_load(load, src, dst, nv, parts)))
 
 
-def test_cluster_carry_kernel_via_engine():
-    """ClusterCarry(use_kernel=True) through run_carry == oracle, bitwise."""
+@pytest.mark.parametrize("rung", ["fused", "tiled"])
+def test_cluster_carry_kernel_via_engine(rung):
+    """ClusterCarry(use_kernel=True) through run_carry == oracle, bitwise,
+    and each chunk counted under the rung it took."""
     from repro.core.clustering import ClusterCarry, compute_degrees
+    from repro.runtime import spans
     from repro.streaming import EdgeStream, run_carry
 
     src, dst, n, _ = _graph(2)
+    if rung == "tiled":
+        n = V_TILED
     deg = compute_degrees(src, dst, n)
     st = EdgeStream(src, dst, n, chunk_size=128)
+    chunks = -(-int(src.shape[0]) // 128)
     kw = dict(xi=3, kappa=max(int(src.shape[0]) // 2, 2))
+    names = [f"stream_scan.cluster.{r}" for r in ("fused", "tiled", "oracle")]
+    for name in names:
+        spans.reset(name)
     _, a = run_carry(st, ClusterCarry(deg, n, use_kernel=True, **kw))
     _, b = run_carry(st, ClusterCarry(deg, n, use_kernel=False, **kw))
     _tree_bitwise(tuple(a), tuple(b), "cluster engine")
+    counts = spans.counters()
+    assert [counts.get(name, 0) for name in names] == [
+        chunks if rung == "fused" else 0, chunks if rung == "tiled" else 0,
+        chunks]
 
 
 def test_assign_carry_kernel_via_engine():
@@ -361,15 +391,26 @@ def test_select_path_gate_boundaries():
             < ss.scoring_state_bytes(V, 128, "hdrf"))
     assert (ss.scoring_state_bytes(97, k, "greedy")
             == ss.scoring_state_bytes(104, k, "greedy"))
-    # the cluster ladder has no tiled rung and is gated by SMEM (1-D
-    # arrays in 1024-word tiles), whatever the VMEM budget
+    # the cluster ladder's fused rung is gated by SMEM (1-D arrays in
+    # 1024-word tiles), whatever the VMEM budget; past it the tiled rung
+    # moves the arrays SMEM cannot hold to VMEM, gated by the budget
     for budget in (None, 1):
         assert ss.select_path(27648, 1, 1 << 16, consumer="cluster",
                               budget=budget) == "fused"
-        assert ss.select_path(27649, 1, 1 << 16, consumer="cluster",
-                              budget=budget) == "oracle"
     assert ss.cluster_state_bytes(27648) <= ss.SMEM_BYTES
     assert ss.cluster_state_bytes(27649) > ss.SMEM_BYTES
+    assert ss.cluster_vmem_arrays(27648) == ()
+    assert ss.cluster_vmem_arrays(27649) == ("alloc_h",)
+    assert ss.cluster_vmem_arrays(1 << 16) == ss.CLUSTER_SMEM_ORDER[3:]
+    need = ss.cluster_state_bytes(27649, tiled=True)
+    assert ss.select_path(27649, 1, 1 << 16, consumer="cluster",
+                          budget=need) == "tiled"
+    assert ss.select_path(27649, 1, 1 << 16, consumer="cluster",
+                          budget=need - 1) == "oracle"
+    assert ss.select_path(27649, 1, 1 << 16, consumer="cluster") == "tiled"
+    # VMEM rows pad to whole (8, 128) tiles: 1,024 vertices a step
+    assert (ss.cluster_state_bytes(1 << 16, tiled=True)
+            == ss.cluster_state_bytes((1 << 16) - 1023, tiled=True))
     # Alg. 3 holds one load row: fused unless the budget cannot hold it
     abytes = ss.assign_state_bytes(k)
     assert ss.select_path(0, k, 1 << 16, consumer="assign",
