@@ -245,6 +245,23 @@ def _s5p_exact(src, dst, n_vertices, k, seed=0, *, stream=None,
     return s5p_partition(src, dst, n_vertices, cfg, stream=stream).parts
 
 
+def _s5p_lanes(src, dst, n_vertices, k, seed=0, *, stream=None,
+               chunk_size=None, num_streams=4, super_chunk="auto",
+               shard="range"):
+    """S5P with S-way parallel ingest (``streaming.parallel``): S loaders
+    each fold their share of the stream, one per device where the host
+    has S, their carries merged by the declared merge laws; then the game
+    once and the touch-up of the clusters two loaders wrote.  The
+    defaults are split-file ingest: 4 loaders, contiguous ranges, the
+    consumer-aware merge cadence."""
+    if num_streams < 2:
+        raise ValueError(f"s5p-lanes needs num_streams >= 2, got "
+                         f"{num_streams}; 's5p' is the one-stream entry")
+    return _s5p(src, dst, n_vertices, k, seed, stream=stream,
+                chunk_size=chunk_size, num_streams=num_streams,
+                super_chunk=super_chunk, shard=shard)
+
+
 PARTITIONERS = {
     "hash": hash_partition,
     "dbh": dbh_partition,
@@ -255,4 +272,5 @@ PARTITIONERS = {
     "clugp": clugp_partition,
     "s5p": _s5p,
     "s5p-exact": _s5p_exact,
+    "s5p-lanes": _s5p_lanes,
 }

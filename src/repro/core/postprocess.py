@@ -12,17 +12,33 @@ pass assigns every edge to a concrete partition under the hard capacity
   the smaller — we follow the listing, which is the balance-preserving
   reading; recorded in DESIGN.md).
 
+Under S ingest lanes (``streaming.parallel``) every lane places against
+the merge base's loads plus its own placements since, so the capacity is
+shared out before each super-step (:meth:`AssignCarry.lane_shares`): the
+room each partition has left, ``L − load``, is split between the lanes in
+proportion to the edges each places before the next merge (rounded
+down); a lane that rounding leaves with fewer slots than edges takes the
+shortfall, lanes in order, from the room left over, partitions in
+ascending order.  A lane treats a partition as full once its own view of
+the load reaches the base load plus its share, and places an edge with
+one full endpoint partition on the other (what the one-capacity rule does
+too, since there the full partition is the more loaded).  So the merged
+loads stay under ``L`` whenever ``k·L`` covers the edges (τ ≥ 1).
+
 Implemented as a jitted ``lax.scan`` with an O(k) carry (the load vector),
 streamed in chunks like Algorithm 1.
 """
 
 from __future__ import annotations
 
+import copy
 from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from ..runtime import spans
 from ..streaming.carry import SUM, PartitionerCarry
 
 __all__ = ["AssignCarry", "assign_edges", "assign_edges_stream"]
@@ -32,12 +48,12 @@ __all__ = ["AssignCarry", "assign_edges", "assign_edges_stream"]
 def _assign_chunk(load, max_load, src, dst, is_head_edge, cu, cv, c2p, *, k: int):
     """One streamed chunk of Algorithm 3.  Returns (load, parts)."""
     arange = jnp.arange(k, dtype=jnp.int32)
-    L = max_load
+    L = jnp.broadcast_to(max_load, (k,))  # one capacity, or one per part
 
     def step(load, edge):
         head, pcu, pcv, valid = edge
-        over_u = load[pcu] >= L
-        over_v = load[pcv] >= L
+        over_u = load[pcu] >= L[pcu]
+        over_v = load[pcv] >= L[pcv]
         room = load < L
         any_room = jnp.any(room)
         first_room = jnp.argmax(room).astype(jnp.int32)
@@ -46,8 +62,12 @@ def _assign_chunk(load, max_load, src, dst, is_head_edge, cu, cv, c2p, *, k: int
         overflow_choice = jnp.where(
             any_room, jnp.where(head, first_room, last_room), fallback
         )
-        # lines 9-10: more-loaded endpoint loses; tie → P_u (line 10 'else')
-        endpoint_choice = jnp.where(load[pcu] > load[pcv], pcv, pcu)
+        # lines 9-10: more-loaded endpoint loses; tie → P_u (line 10 'else');
+        # a full endpoint always loses (with one capacity for every part
+        # the full one is the more loaded, so this is the same rule)
+        endpoint_choice = jnp.where(
+            over_u != over_v, jnp.where(over_u, pcv, pcu),
+            jnp.where(load[pcu] > load[pcv], pcv, pcu))
         part = jnp.where(over_u & over_v, overflow_choice, endpoint_choice)
         load = load + jnp.where(valid, (arange == part).astype(load.dtype), 0)
         return load, jnp.where(valid, part, -1)
@@ -66,7 +86,8 @@ class AssignCarry(PartitionerCarry):
     cluster→partition map and capacity are replicated closure constants.
     Under parallel ingest each sub-stream places its edges against a load
     vector that is ``super_chunk`` chunks stale at worst — the bounded-
-    staleness regime of ``core.distributed`` Phase 4.
+    staleness regime of ``core.distributed`` Phase 4 — and under its own
+    share of the capacity (module doc), so no merged load passes ``L``.
     """
 
     merge_ops = (SUM,)
@@ -86,6 +107,31 @@ class AssignCarry(PartitionerCarry):
 
     def init(self) -> jax.Array:
         return jnp.zeros((self.k,), jnp.int32)
+
+    def lane_shares(self, base, demand):
+        """Each lane's per-partition load limits until the next merge, (S,
+        k) int32: the base load plus the lane's share of the room left
+        (module doc)."""
+        load = spans.to_host(base).astype(np.int64)
+        n = np.asarray(demand, np.int64)
+        room = np.maximum(int(self.max_load) - load, 0)
+        share = room[None, :] * n[:, None] // max(int(n.sum()), 1)
+        left = room - share.sum(axis=0)
+        for s in range(n.size):
+            short = n[s] - share[s].sum()
+            for p in np.flatnonzero(left):
+                if short <= 0:
+                    break
+                take = min(short, left[p])
+                share[s, p] += take
+                left[p] -= take
+                short -= take
+        return (load[None, :] + share).astype(np.int32)
+
+    def for_lane(self, share):
+        lane = copy.copy(self)
+        lane.max_load = jnp.asarray(share, jnp.int32)
+        return lane
 
     def _kernel_path(self, chunk_size):
         # lazy import (core.baselines ↔ kernels layering, see clustering)
@@ -145,6 +191,7 @@ def assign_edges_stream(
     num_streams: int = 1,
     super_chunk: int | str = 8,
     shard: str = "range",
+    plan=None,
     use_kernel: bool | None = None,
     vmem_budget: int | None = None,
 ):
@@ -154,7 +201,9 @@ def assign_edges_stream(
     EdgeStream as extras, so a reordered stream keeps them aligned; parts
     come back in arrival order either way.  ``num_streams > 1`` places S
     sharded sub-streams in parallel with load-vector all-reduces every
-    ``super_chunk`` chunks (``num_streams=1`` is bit-identical sequential).
+    ``super_chunk`` chunks (``num_streams=1`` is bit-identical sequential),
+    each lane under its share of the capacity; ``plan`` is the caller's
+    :class:`~repro.streaming.parallel.ParallelEdgeStream` of the stream.
     """
     from ..streaming import as_stream, run_parallel
 
@@ -163,7 +212,8 @@ def assign_edges_stream(
                      vmem_budget=vmem_budget)
     parts, load = run_parallel(
         stream, pc, is_head_edge, cu, cv,
-        num_streams=num_streams, super_chunk=super_chunk, shard=shard)
+        num_streams=num_streams, super_chunk=super_chunk, shard=shard,
+        plan=plan)
     return parts, load
 
 

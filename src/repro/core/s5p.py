@@ -294,11 +294,15 @@ def _s5p_job(src, dst, n_vertices, config, stream):
     max_load = _INT32_MAX if config.bounded else int(math.ceil(config.tau * E / k))
     with spans.span("s5p.alg3") as sp:
         cu, cv, is_head = _edge_clusters(src, dst, res, degrees, xi)
+        # the placement's lane plan, kept for the touch-up's provenance
+        plan = (_stream.ParallelEdgeStream(stream, config.num_streams,
+                                           shard=config.shard)
+                if config.num_streams > 1 else None)
         parts, load = sp.wait_for(_post.assign_edges_stream(
             src, dst, is_head, jnp.maximum(cu, 0), jnp.maximum(cv, 0),
             game.assignment, k, max_load, stream=stream,
             num_streams=config.num_streams, super_chunk=config.super_chunk,
-            shard=config.shard,
+            shard=config.shard, plan=plan,
             use_kernel=config.use_kernel, vmem_budget=config.vmem_budget,
         ))
     ingest = _stream.last_ingest_stats()  # the placement pass's drive
@@ -311,7 +315,7 @@ def _s5p_job(src, dst, n_vertices, config, stream):
             and config.refine_rounds > 0 and res.n_clusters > 1):
         with spans.span("s5p.touch_up") as sp:
             parts, load, c2p, tu_stats = sp.wait_for(_touch_up(
-                src, dst, n_vertices, config, stream, res, inputs, bs,
+                src, dst, n_vertices, config, plan, res, inputs, bs,
                 cu, cv, is_head, sizes, parts, load, c2p, k, max_load))
         stats["touch_up"] = tu_stats
 
@@ -344,21 +348,20 @@ def _s5p_job(src, dst, n_vertices, config, stream):
     )
 
 
-def _touch_up(src, dst, n_vertices, config, stream, res, inputs, bs,
+def _touch_up(src, dst, n_vertices, config, plan, res, inputs, bs,
               cu, cv, is_head, sizes, parts, load, c2p, k, max_load):
     """One bounded masked-game pass over the clusters whose membership was
     written by ≥ 2 ingest lanes — the only clusters whose carry state could
     have gone stale across lanes — then re-place exactly those clusters'
     edges (the ``_refine_pass`` recipe of ``repro.incremental``): lift the
     moved edges out of the load vector and replay them in arrival order
-    against the refined cluster→partition table."""
+    against the refined cluster→partition table.  Counts
+    ``s5p.touch_up.contested``, ``.moved`` (clusters) and
+    ``.replayed_edges``."""
     C = res.n_clusters
-    # provenance: which lane folded each edge (the plan is deterministic,
-    # so rebuilding it gives exactly the lanes the ingest used — no need
-    # to have carried per-edge lane ids through the passes)
-    ps = _stream.ParallelEdgeStream(stream, config.num_streams,
-                                    shard=config.shard)
-    lanes = ps.edge_lanes()
+    # provenance: which lane folded each edge, from the placement pass's
+    # own plan (every pass of the job shards the stream the same way)
+    lanes = plan.edge_lanes()
     cu_np = spans.to_host(cu)
     cv_np = spans.to_host(cv)
     valid = spans.to_host(src != dst)
@@ -373,6 +376,7 @@ def _touch_up(src, dst, n_vertices, config, stream, res, inputs, bs,
     move_mask = contested & (spans.to_host(sizes) > 0)
     stats = {"contested_clusters": int(contested.sum()), "moved_clusters": 0,
              "replayed_edges": 0, "rounds": 0}
+    spans.count("s5p.touch_up.contested", stats["contested_clusters"])
     if not move_mask.any():
         return parts, load, c2p, stats
     refined = _game.run_game(
@@ -386,6 +390,7 @@ def _touch_up(src, dst, n_vertices, config, stream, res, inputs, bs,
     c2p_new = spans.to_host(refined.assignment)
     moved = np.flatnonzero(c2p_new != c2p)
     stats["moved_clusters"] = int(moved.size)
+    spans.count("s5p.touch_up.moved", stats["moved_clusters"])
     if not moved.size:
         return parts, load, c2p, stats
     moved_mask = np.zeros(C, bool)
@@ -394,6 +399,7 @@ def _touch_up(src, dst, n_vertices, config, stream, res, inputs, bs,
                    | moved_mask[np.maximum(cv_np, 0)])
     aidx = np.flatnonzero(aff)
     stats["replayed_edges"] = int(aidx.size)
+    spans.count("s5p.touch_up.replayed_edges", stats["replayed_edges"])
     parts_np = spans.to_host(parts).copy()
     load64 = spans.to_host(load).astype(np.int64)
     np.subtract.at(load64, parts_np[aidx], 1)

@@ -11,7 +11,8 @@ compiler lays it out:
   SMEM use is therefore set by ``block``, not by the chunk length.  1-D
   int32 arrays tile by 1024 on the chip, so ``block`` is 1024 (or the
   whole chunk, when it is shorter);
-- the only scalar prefetch is the ``meta`` vector (limit, sign, cap);
+- the only scalar prefetch is the ``meta`` vector (limit, sign, cap;
+  Alg. 3 under ingest lanes takes a ``(1, W)`` row of caps instead);
 - the greedy/HDRF per-vertex state is **one packed int32 row per vertex**,
   ``W = roundup(k + [hdrf], 128)`` lanes wide: lanes ``[0, k)`` hold the
   counted replica table, lane ``k`` holds HDRF's partial degree, the rest
@@ -675,7 +676,11 @@ def cluster_scan(state, src, dst, degrees, *, xi: int, kappa: int,
 
 
 def _assign_kernel(meta_ref, src_ref, dst_ref, head_ref, pcu_ref, pcv_ref,
-                   pin_ref, load_in, parts_ref, load_ref, *, k, block):
+                   pin_ref, load_in, *refs, k, block, per_part_cap):
+    if per_part_cap:
+        cap_ref, parts_ref, load_ref = refs
+    else:
+        parts_ref, load_ref = refs
     i = pl.program_id(0)
 
     @pl.when(i == 0)
@@ -701,9 +706,15 @@ def _assign_kernel(meta_ref, src_ref, dst_ref, head_ref, pcu_ref, pcv_ref,
         load = load_ref[...]
         lu = jnp.sum(jnp.where(lane == pcu, load, 0))
         lv = jnp.sum(jnp.where(lane == pcv, load, 0))
-        over_u = lu >= cap
-        over_v = lv >= cap
-        room = (load < cap) & real_lane
+        if per_part_cap:  # one capacity per partition (an ingest lane's)
+            caps = cap_ref[...]
+            over_u = lu >= jnp.sum(jnp.where(lane == pcu, caps, 0))
+            over_v = lv >= jnp.sum(jnp.where(lane == pcv, caps, 0))
+            room = (load < caps) & real_lane
+        else:
+            over_u = lu >= cap
+            over_v = lv >= cap
+            room = (load < cap) & real_lane
         any_room = _any(room)
         first_room = jnp.min(jnp.where(room, lane, W))
         # integer-equal to the oracle's k-1-argmax(room[::-1]) whenever
@@ -713,6 +724,10 @@ def _assign_kernel(meta_ref, src_ref, dst_ref, head_ref, pcu_ref, pcv_ref,
         overflow_choice = jnp.where(
             any_room, jnp.where(head, first_room, last_room), fallback)
         endpoint_choice = jnp.where(lu > lv, pcv, pcu)
+        if per_part_cap:  # one endpoint full: the other, whatever the loads
+            endpoint_choice = jnp.where(
+                over_u != over_v, jnp.where(over_u, pcv, pcu),
+                endpoint_choice)
         part_ins = jnp.where(over_u & over_v, overflow_choice,
                              endpoint_choice)
         p_ret = pin_ref[e]
@@ -728,16 +743,21 @@ def _assign_kernel(meta_ref, src_ref, dst_ref, head_ref, pcu_ref, pcv_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("k", "block", "interpret"))
-def _assign_call(meta, src, dst, head, pcu, pcv, pin, load, *, k, block,
-                 interpret):
+def _assign_call(meta, src, dst, head, pcu, pcv, pin, load, caps=None, *, k,
+                 block, interpret):
+    """``caps``, a (1, W) row, replaces ``meta``'s one capacity with one
+    per partition."""
     Epad = src.shape[0]
     W = load.shape[1]
     edge = _edge_spec(block)
-    kernel = functools.partial(_assign_kernel, k=k, block=block)
+    per_part_cap = caps is not None
+    kernel = functools.partial(_assign_kernel, k=k, block=block,
+                               per_part_cap=per_part_cap)
+    rows = [_const_spec((1, W))] * (2 if per_part_cap else 1)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(Epad // block,),
-        in_specs=[edge] * 6 + [_const_spec((1, W))],
+        in_specs=[edge] * 6 + rows,
         out_specs=[edge, _const_spec((1, W))],
     )
     return pl.pallas_call(
@@ -748,7 +768,8 @@ def _assign_call(meta, src, dst, head, pcu, pcv, pin, load, *, k, block,
         input_output_aliases={7: 1},
         interpret=interpret,
         name="assign",
-    )(meta, src, dst, head, pcu, pcv, pin, load)
+    )(meta, src, dst, head, pcu, pcv, pin, load,
+      *([caps] if per_part_cap else []))
 
 
 def assign_scan(load, src, dst, is_head_edge, pcu, pcv, *, max_load,
@@ -757,7 +778,8 @@ def assign_scan(load, src, dst, is_head_edge, pcu, pcv, *, max_load,
     """One fused Algorithm-3 chunk — insert or retract.
 
     ``pcu``/``pcv`` are the endpoint **partition** ids (``c2p`` gathered
-    outside, exactly as the oracle does).  Returns ``(parts, load)``.
+    outside, exactly as the oracle does).  ``max_load`` is one capacity
+    or a (k,) vector of one per partition.  Returns ``(parts, load)``.
     Mirrors ``core.postprocess._assign_chunk`` / ``_retract_load``.
     """
     if sign not in (1, -1):
@@ -781,12 +803,16 @@ def assign_scan(load, src, dst, is_head_edge, pcu, pcv, *, max_load,
         pcv = jnp.pad(pcv, (0, pad))
     src, dst, pin = _pad_edges(src, dst, parts, pad)
     limit = jnp.asarray(E if sign > 0 else n_valid, jnp.int32)
-    meta = jnp.stack([limit, jnp.int32(sign),
-                      jnp.asarray(max_load, jnp.int32)])
+    max_load = jnp.asarray(max_load, jnp.int32)
     W = table_width(k, "assign")
+    caps = None
+    if max_load.ndim:
+        caps = jnp.pad(max_load, (0, W - k)).reshape(1, W)
+        max_load = jnp.int32(0)
+    meta = jnp.stack([limit, jnp.int32(sign), max_load])
     spans.count(_DISPATCHES)
     parts_out, load2 = _assign_call(
         meta, src, dst, head, pcu, pcv, pin,
-        jnp.pad(load, (0, W - k)).reshape(1, W), k=k, block=blk,
+        jnp.pad(load, (0, W - k)).reshape(1, W), caps, k=k, block=blk,
         interpret=interpret)
     return parts_out[:E], load2[0, :k]

@@ -178,6 +178,21 @@ class PartitionerCarry:
     def finalize(self, carry):
         return carry
 
+    # ---------------------------------------------------------- lane views
+    def lane_shares(self, base, demand):
+        """What each ingest lane may use of a bounded resource until the
+        next merge, or None when the consumer bounds nothing.  ``base`` is
+        the merge base the lanes fold from and ``demand[s]`` the edges lane
+        s folds before the next merge; the result is a host array with one
+        row per lane, handed to :meth:`for_lane`.  Every parallel backend
+        calls this at every merge base, so they stay bit-identical."""
+        return None
+
+    def for_lane(self, share):
+        """This consumer as a lane with ``share`` (one row of
+        :meth:`lane_shares`) folds it; ``share`` may be traced."""
+        return self
+
     # -------------------------------------------------------- group algebra
     def signed_delta(self, after, before):
         """The group difference ``after ⊖ before`` per field.
@@ -350,6 +365,22 @@ class PartitionerCarry:
             if seen:
                 return changed / max(active, 1)
         return 0.0
+
+    def merge_bytes(self, carry) -> int:
+        """Bytes one lane hands to one merge's collectives
+        (:meth:`merge_collective`): each SUM/COUNTED field's delta, twice
+        for a :attr:`pick_first` field (its lowest-writer ``pmin``, then
+        the ``psum``), each OR/MAX field (bools as int32); REPLICATED
+        fields are not exchanged."""
+        total = 0
+        for i, (op, x) in enumerate(zip(self.merge_ops,
+                                        jax.tree_util.tree_leaves(carry))):
+            if op == REPLICATED:
+                continue
+            x = jnp.asarray(x)
+            n = x.size * (4 if x.dtype == jnp.bool_ else x.dtype.itemsize)
+            total += 2 * n if op in GROUP_OPS and i in self.pick_first else n
+        return total
 
     def merge_collective(self, local, base, axis: str):
         """The shard_map form of :meth:`merge`: one collective per field

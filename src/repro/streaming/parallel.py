@@ -11,10 +11,10 @@ state per merge, never edges.
 :class:`ParallelEdgeStream` is the sharding plan: it slices any
 :class:`~repro.streaming.stream.EdgeStream` (in-memory or the mmap-paged
 ``ShardedEdgeStream``) into S logical sub-streams and serves lockstep
-*rounds* — the r-th chunk of every sub-stream, stacked into (S, B) device
-arrays (lanes that ran out of chunks serve all-padding (0, 0) self-loop
-chunks, the masked no-op every consumer already skips).  Three shard
-modes:
+*rounds* — the r-th chunk of every sub-stream, staged on the host as
+lane-major (S, R, B) blocks of R rounds (lanes that ran out of chunks
+serve all-padding (0, 0) self-loop chunks, the masked no-op every
+consumer already skips).  Three shard modes:
 
 - ``"range"``       — chunk-granular: lane s scans the contiguous chunk
   range ``[s·⌈C/S⌉, (s+1)·⌈C/S⌉)`` (the HEP file-split layout);
@@ -54,12 +54,26 @@ integer/bool exact, so reduction order cannot matter):
   first S local devices by default, or any provided mesh); the super-chunk
   merge becomes one ``psum``/``pmax`` collective per carry field — the
   same collective plumbing ``core.distributed`` uses.  The default when
-  the platform reports ≥ S devices.  (Note: *forced* host-platform CPU
-  devices execute serially — real parallelism needs real devices or the
-  threads backend.)
+  the platform reports ≥ S devices.  A super-step (``lanes_super_step``)
+  takes the consumer's per-job arrays as arguments, so it compiles once
+  per shape and serves every later job.  (Note: *forced* host-platform
+  CPU devices execute serially — real parallelism needs real devices or
+  the threads backend.)
 - ``"vmap"``      — one compiled step processes all S lanes per round as
   a batch.  Semantically the reference backend; on XLA:CPU the batched
   per-edge scatters lower poorly, so use it for testing, not speed.
+
+A consumer that bounds a resource (Alg. 3's partition capacity) shares
+it out between the lanes at every merge base
+(:meth:`~repro.streaming.carry.PartitionerCarry.lane_shares`), so a bound
+the sequential scan keeps holds for the merged carry too.
+
+A drive with S > 1 lanes is the span ``lanes.drive``; the vmap and
+shard_map backends stage each super-step's blocks, and the lanes' shares
+read from the merge base, under ``lanes.stage``.
+Every merge counts ``lanes.merges``, ``lanes.rounds`` (chunks per lane
+since the last merge) and ``lanes.merge_bytes`` (the carry bytes each
+lane hands to it), on the host.
 
 ``num_streams=1`` (or a single-chunk stream) bypasses all of this and runs
 the sequential :func:`~repro.streaming.engine.run_carry` driver — the
@@ -70,6 +84,7 @@ shard mode.
 
 from __future__ import annotations
 
+import copy
 import dataclasses
 import logging
 import math
@@ -81,6 +96,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..runtime import spans
 from .carry import PartitionerCarry
 from .engine import run_carry
 from .stream import Chunk, EdgeStream
@@ -432,73 +448,74 @@ class ParallelEdgeStream:
         cs, E = self.stream.chunk_size, self.stream.n_edges
         return min((chunk_id + 1) * cs, E) - chunk_id * cs
 
+    def chunk_positions(self, chunk_id: int):
+        """The stream positions a plan chunk's valid edges occupy."""
+        if self._chunk_pos is not None:
+            return self._chunk_pos[chunk_id]
+        start = chunk_id * self.stream.chunk_size
+        return slice(start, start + self.chunk_n_valid(chunk_id))
+
+    def _chunk_edges(self, chunk_id: int, *extras):
+        """``(src, dst, extras)`` of a plan chunk's valid edges, unpadded,
+        as the stream and the extras hold them."""
+        st = self.stream
+        pos = self.chunk_positions(chunk_id)
+        if isinstance(pos, slice):
+            sl = pos if st.order is None else np.asarray(st.order[pos])
+            s, d = st._edges_at(sl, pos.start, pos.stop)
+        else:
+            sl = pos if st.order is None else np.asarray(st.order)[pos]
+            s, d = st._edges_at(np.asarray(sl), 0, len(pos))
+        return s, d, [e[sl] for e in extras]
+
     def chunk_for(self, chunk_id: int, *extras) -> Chunk:
         """The chunk behind a plan chunk id: the stream's own chunk in the
         chunk-granular modes, a gathered synthetic chunk in hub mode."""
         if self._chunk_pos is None:
             return self.stream.chunk_at(chunk_id, *extras)
-        st = self.stream
-        pos = self._chunk_pos[chunk_id]
-        B = st.chunk_size
-        arr = pos if st.order is None else np.asarray(st.order)[pos]
-        ex = [e if hasattr(e, "shape") else np.asarray(e) for e in extras]
-        s, d = st._edges_at(np.asarray(arr), 0, len(pos))
+        B = self.stream.chunk_size
+        ex = [np.asarray(e) for e in extras]
+        s, d, exc = self._chunk_edges(chunk_id, *ex)
         s = np.asarray(s, np.int32)
         d = np.asarray(d, np.int32)
-        exc = [np.asarray(e)[arr] for e in ex]
-        nv = len(pos)
+        nv = len(s)
         if nv < B:  # pad to the fixed chunk size ((0,0) self-loop no-ops)
             padn = B - nv
             s = np.concatenate([s, np.zeros(padn, np.int32)])
             d = np.concatenate([d, np.zeros(padn, np.int32)])
             exc = [np.concatenate(
                 [e, np.zeros((padn,) + e.shape[1:], e.dtype)]) for e in exc]
+        pos = self._chunk_pos[chunk_id]
         return Chunk(src=jnp.asarray(s), dst=jnp.asarray(d),
                      extras=tuple(jnp.asarray(e) for e in exc),
                      start=int(pos[0]) if nv else 0, n_valid=nv)
 
-    def round_at(self, r: int, *extras):
-        """Round r as stacked (S, B) arrays.
-
-        Returns ``(src, dst, n_valid (S,), extras, chunk_ids)`` where
-        ``chunk_ids[s]`` is the plan chunk served to lane s this round
-        (``None`` for exhausted lanes, which get all-padding chunks).
-        """
-        B = self.stream.chunk_size
-        srcs, dsts, nvs, ids = [], [], [], []
-        exs: list[list] = [[] for _ in extras]
-        zero = None
-        for lane in self.lanes:
-            if r < len(lane):
-                cid = lane[r]
-                ch = self.chunk_for(cid, *extras)
-                if ch.src.shape[0] != B:  # single-chunk streams never get here
-                    raise AssertionError("parallel rounds need fixed-size chunks")
-                srcs.append(ch.src)
-                dsts.append(ch.dst)
-                nvs.append(ch.n_valid)
-                for j, e in enumerate(ch.extras):
-                    exs[j].append(e)
-                ids.append(cid)
-            else:  # exhausted lane: all-padding (0, 0) self-loop chunk
-                if zero is None:
-                    zero = jnp.zeros((B,), jnp.int32)
-                srcs.append(zero)
-                dsts.append(zero)
-                nvs.append(0)
-                for j, e in enumerate(exs):
-                    proto = e[0] if e else None
-                    if proto is None:
-                        raise AssertionError("padding lane before any real lane")
-                    e.append(jnp.zeros_like(proto))
-                ids.append(None)
-        return (
-            jnp.stack(srcs),
-            jnp.stack(dsts),
-            jnp.asarray(np.array(nvs, np.int32)),
-            tuple(jnp.stack(e) for e in exs),
-            ids,
-        )
+    def block(self, rounds, *extras):
+        """Rounds ``rounds`` of every lane as host arrays, lane-major:
+        ``((src (S, R, B), dst, n_valid (S, R), *extras (S, R, B, ...)),
+        ids)``, where ``ids[ri][s]`` is the plan chunk lane s folds in round
+        ``rounds[ri]``.  A chunk's tail past its valid edges, and the whole
+        chunk of a lane that ran out of chunks (id ``None``, ``n_valid``
+        0), are (0, 0) self-loops with zero extras: the padding every
+        consumer skips.  ``extras`` are host arrays in arrival order."""
+        S, R, B = self.num_streams, len(rounds), self.stream.chunk_size
+        src = np.zeros((S, R, B), np.int32)
+        dst = np.zeros((S, R, B), np.int32)
+        nv = np.zeros((S, R), np.int32)
+        exs = [np.zeros((S, R, B) + e.shape[1:], e.dtype) for e in extras]
+        ids: list[list[int | None]] = [[None] * S for _ in range(R)]
+        for s, lane in enumerate(self.lanes):
+            for ri, r in enumerate(rounds):
+                if r >= len(lane):
+                    continue
+                cid = ids[ri][s] = lane[r]
+                cs, cd, cex = self._chunk_edges(cid, *extras)
+                n = nv[s, ri] = len(cs)
+                src[s, ri, :n] = cs
+                dst[s, ri, :n] = cd
+                for j, e in enumerate(cex):
+                    exs[j][s, ri, :n] = e
+        return (src, dst, nv, *exs), ids
 
 
 def _mask_inactive_step(pc):
@@ -523,14 +540,6 @@ def _device_of(tree) -> int:
     """Id of the (single) device holding a carry's first leaf."""
     leaf = jax.tree_util.tree_leaves(tree)[0]
     return min(d.id for d in leaf.devices())
-
-
-def _lane_devices(stacked) -> list[int]:
-    """Device id of each lane's row of a lane-sharded (S, ...) carry."""
-    leaf = jax.tree_util.tree_leaves(stacked)[0]
-    shards = sorted(leaf.addressable_shards,
-                    key=lambda sh: sh.index[0].start or 0)
-    return [sh.device.id for sh in shards]
 
 
 def _resolve_backend(backend, S):
@@ -567,6 +576,7 @@ def run_parallel(
     carry_store=None,
     carry_consumer: str | None = None,
     carry_config=None,
+    plan: ParallelEdgeStream | None = None,
 ):
     """Drive ``pc`` over ``stream`` with S-way parallel ingest.
 
@@ -584,8 +594,14 @@ def run_parallel(
     ``carry`` seeds the drive from a restored carry instead of
     ``pc.init()`` (the warm-start replay of ``repro.incremental``) — it
     becomes the first merge base, so SUM fields never double-count the
-    restored state.  Per-lane stats and the realized cadence schedule
-    are published through :func:`last_ingest_stats`.
+    restored state.  ``plan`` is a :class:`ParallelEdgeStream` of
+    ``stream`` the caller built with these ``num_streams``, ``shard`` and
+    ``hub_threshold``, used in place of a new one (so the caller knows
+    which lane folded each edge).  A consumer that bounds a resource
+    (:meth:`~repro.streaming.carry.PartitionerCarry.lane_shares`) gets
+    each lane's share of it at every merge base.  Per-lane stats and the
+    realized cadence schedule are published through
+    :func:`last_ingest_stats`.
 
     Fault/straggler hardening (threads backend):
 
@@ -642,217 +658,257 @@ def run_parallel(
                              wall_s=time.perf_counter() - t0),)))
         return out
 
-    ps = ParallelEdgeStream(stream, num_streams, shard=shard,
-                            hub_threshold=hub_threshold)
-    S = ps.num_streams
-    backend = _resolve_backend(backend, S)
-    wants_fault_path = (lane_injector is not None or straggler is not None
-                        or carry_store is not None
-                        or on_lane_failure != "raise")
-    if wants_fault_path and backend != "threads":
-        raise ValueError(
-            "lane fault handling / straggler handoff / carry checkpoints "
-            f"run on the threads backend (host workers die independently); "
-            f"got backend={backend!r}")
-    base = pc.init() if carry is None else carry
-    parts_by_chunk: dict[int, jax.Array] = {}
-    ctl = _CadenceController(pc, super_chunk)
-    t_run = time.perf_counter()
-    lane_chunks = [0] * S
-    lane_edges = [0] * S
-    lane_wall = [0.0] * S
-    lane_dev: list[int | None] = [None] * S
-
-    if backend == "vmap":
-        n_ex = len(extras)
-        # jit the vmapped step once per drive: rounds reuse one executable
-        vstep = jax.jit(jax.vmap(_mask_inactive_step(pc),
-                                 in_axes=(0, 0, 0, 0) + (0,) * n_ex))
-        r0 = 0
-        while r0 < ps.n_rounds:
-            sc = ctl.next()
-            local = jax.tree_util.tree_map(
-                lambda x: jnp.broadcast_to(
-                    jnp.asarray(x), (S,) + jnp.shape(jnp.asarray(x))), base)
-            for r in range(r0, min(r0 + sc, ps.n_rounds)):
-                src, dst, nv, exs, ids = ps.round_at(r, *extras)
-                local, parts = vstep(local, src, dst, nv, *exs)
-                if parts is not None:
-                    for s, cid in enumerate(ids):
-                        if cid is not None:
-                            parts_by_chunk[cid] = parts[s]
-            prev = base
-            base = pc.merge_stacked(local, prev)
-            ctl.observe(prev, base)
-            lane_dev = [_device_of(local)] * S  # one program, one device
-            r0 += sc
-    elif backend == "shard_map":
-        mesh = mesh if mesh is not None else _streams_mesh(S)
-        axis = mesh.axis_names[0]
-        if mesh.shape[axis] != S:
+    with spans.span("lanes.drive") as drive:
+        ps = plan if plan is not None else ParallelEdgeStream(
+            stream, num_streams, shard=shard, hub_threshold=hub_threshold)
+        S = ps.num_streams
+        backend = _resolve_backend(backend, S)
+        wants_fault_path = (lane_injector is not None or straggler is not None
+                            or carry_store is not None
+                            or on_lane_failure != "raise")
+        if wants_fault_path and backend != "threads":
             raise ValueError(
-                f"shard_map backend needs a {S}-wide mesh axis, got "
-                f"{mesh.shape[axis]} (use backend='threads' or 'vmap' on "
-                f"hosts with fewer devices)")
-        fns: dict[int, object] = {}  # jitted super-step per round count
-        r0 = 0
-        while r0 < ps.n_rounds:
-            sc = ctl.next()
-            rounds = list(range(r0, min(r0 + sc, ps.n_rounds)))
-            blocks = [ps.round_at(r, *extras) for r in rounds]
-            # (S, R, B) lane-major blocks for this super-chunk
-            src_b = jnp.stack([b[0] for b in blocks], axis=1)
-            dst_b = jnp.stack([b[1] for b in blocks], axis=1)
-            nv_b = jnp.stack([b[2] for b in blocks], axis=1)
-            exs_b = tuple(
-                jnp.stack([b[3][j] for b in blocks], axis=1)
-                for j in range(len(extras)))
-            R = len(rounds)
-            if R not in fns:
-                fns[R] = _make_super_step(pc, mesh, axis, R, base,
-                                          len(extras))
-            prev = base
-            base, parts_b = fns[R](prev, src_b, dst_b, nv_b, *exs_b)
-            lane_dev = _lane_devices(base)
-            base = jax.tree_util.tree_map(lambda x: x[0], base)
-            ctl.observe(prev, base)
-            if pc.emits_parts:
-                for ri, r in enumerate(rounds):
-                    ids = blocks[ri][4]
-                    for s, cid in enumerate(ids):
-                        if cid is not None:
-                            parts_by_chunk[cid] = parts_b[s, ri]
-            r0 += sc
-    elif backend == "threads":
-        # S host workers fold their sub-streams concurrently through the
-        # shared compiled step (execution releases the GIL); chunk staging
-        # is serialized under one lock — the out-of-core stream's budget
-        # accounting and staging buffers are not thread-safe, and staging
-        # is a small fraction of a chunk's scan cost.
-        stage_lock = threading.Lock()
-        # lanes are mutable here: straggler handoff re-deals remaining
-        # chunks between merge boundaries (the sharding plan's own lists
-        # stay pristine in the chunk-granular modes; hub mode re-registers
-        # synthetic chunks, pin map updated in place)
-        lanes = [list(lane) for lane in ps.lanes]
-        pos = [0] * S  # per-lane cursor into its (possibly re-dealt) list
-        edges_done = 0  # edges committed through merges (checkpoint key)
-        consumer = (carry_consumer if carry_consumer is not None
-                    else f"parallel:{type(pc).__name__}")
-        store_cfg = dict(carry_config or {})
-        store_cfg.setdefault("super_chunk", str(super_chunk))
-        store_cfg.setdefault("shard", shard)
+                "lane fault handling / straggler handoff / carry checkpoints "
+                "run on the threads backend (host workers die "
+                f"independently); got backend={backend!r}")
+        base = pc.init() if carry is None else carry
+        # per plan chunk: its parts, valid edges only (on the host, or on the
+        # device on the threads backend)
+        parts_by_chunk: dict = {}
+        ctl = _CadenceController(pc, super_chunk)
+        t_run = time.perf_counter()
+        lane_chunks = [0] * S
+        lane_edges = [0] * S
+        lane_wall = [0.0] * S
+        lane_dev: list[int | None] = [None] * S
 
-        def lane_fold(lane_id, chunks, start, inject):
-            local = start
-            t0 = time.perf_counter()
-            for cid in chunks:
-                if inject is not None:
-                    inject.check(lane_id, cid)
-                with stage_lock:
-                    ch = ps.chunk_for(cid, *extras)
-                local, parts = pc.step_chunk(
-                    local, ch.src, ch.dst, jnp.int32(ch.n_valid), *ch.extras)
-                if parts is not None:
-                    parts_by_chunk[cid] = parts[: ch.n_valid]
-            lane_dev[lane_id] = _device_of(local)
-            return local, time.perf_counter() - t0
-
-        def save_base(carry_val):
-            if carry_store is not None:
-                carry_store.save(carry_val, consumer=consumer,
-                                 config=store_cfg, stream_pos=edges_done)
-
-        def restore_base():
-            if carry_store is None:
-                return base  # in-memory merge base == last commit point
-            restored, _ = carry_store.load(like=base, consumer=consumer,
-                                           config=store_cfg,
-                                           max_stream_pos=edges_done)
-            return restored
-
-        save_base(base)  # a lane can die before the first merge commits
-        sc_index = 0
-        with ThreadPoolExecutor(max_workers=S) as ex:
-            while any(pos[s] < len(lanes[s]) for s in range(S)):
+        if backend in ("vmap", "shard_map"):
+            # the lanes' per-edge extras come to the host once; every
+            # super-step's (S, R, B) blocks are staged there
+            host_extras = [spans.to_host(e) for e in extras]
+            vstep = None
+            if backend == "shard_map":
+                mesh = mesh if mesh is not None else _streams_mesh(S)
+                axis = mesh.axis_names[0]
+                if mesh.shape[axis] != S:
+                    raise ValueError(
+                        f"shard_map backend needs a {S}-wide mesh axis, got "
+                        f"{mesh.shape[axis]} (use backend='threads' or 'vmap' "
+                        f"on hosts with fewer devices)")
+                P = jax.sharding.PartitionSpec
+                by_lane = jax.sharding.NamedSharding(mesh, P(axis))
+                replicated = jax.sharding.NamedSharding(mesh, P())
+                arrays = jax.device_put(
+                    tuple(_split_consumer(pc)[0].values()), replicated)
+                base = jax.device_put(base, replicated)
+                lane_dev = [d.id for d in mesh.devices.flat]
+            r0 = 0
+            while r0 < ps.n_rounds:
                 sc = ctl.next()
-                batches = [lanes[s][pos[s]:pos[s] + sc] for s in range(S)]
-                futs = [ex.submit(lane_fold, s, batches[s], base,
-                                  lane_injector) for s in range(S)]
-                locals_: list = [None] * S
-                times = [0.0] * S
-                failed: list[int] = []
-                for s, f in enumerate(futs):
-                    try:
-                        locals_[s], times[s] = f.result()
-                    except Exception as e:  # noqa: BLE001 — lane death
-                        if on_lane_failure != "replay":
-                            raise
-                        log.warning("ingest lane %d died mid-super-chunk "
-                                    "(%s); replaying its range", s, e)
-                        failed.append(s)
-                for s in failed:
-                    # replay the dead lane's chunk range from the last
-                    # committed base into a surviving worker — the merge
-                    # below can't tell the difference (bit-identical)
-                    locals_[s], times[s] = ex.submit(
-                        lane_fold, s, batches[s], restore_base(),
-                        None).result()
+                rounds = range(r0, min(r0 + sc, ps.n_rounds))
+                R = len(rounds)
+                with spans.span("lanes.stage") as sp:
+                    host_blk, ids = ps.block(rounds, *host_extras)
+                    shares = _shares(pc, base, host_blk[2].sum(axis=1))
+                    blk = (*shares, *host_blk)
+                    if backend == "shard_map":
+                        blk = sp.wait_for(jax.device_put(blk, by_lane))
                 prev = base
-                base = pc.merge(locals_, base=prev)
+                if backend == "shard_map":
+                    step = _super_step(pc, mesh, axis, R, base, len(extras),
+                                       len(shares))
+                    base, parts = step(arrays, prev, *blk)
+                else:
+                    if vstep is None:  # one executable for every round
+                        vstep = jax.jit(jax.vmap(
+                            _lane_step(pc, len(shares))))
+                    local = jax.tree_util.tree_map(
+                        lambda x: jnp.broadcast_to(
+                            jnp.asarray(x), (S,) + jnp.shape(jnp.asarray(x))),
+                        prev)
+                    per_round = []
+                    for ri in range(R):
+                        local, p = vstep(local, *shares,
+                                         *(x[:, ri] for x in host_blk))
+                        per_round.append(p)
+                    base = pc.merge_stacked(local, prev)
+                    # one program, one device
+                    lane_dev = [_device_of(local)] * S
+                    parts = (jnp.stack(per_round, axis=1) if pc.emits_parts
+                             else None)
                 ctl.observe(prev, base)
-                edges_done += sum(ps.chunk_n_valid(cid)
-                                  for b in batches for cid in b)
-                for s in range(S):
-                    pos[s] += len(batches[s])
-                    lane_chunks[s] += len(batches[s])
-                    lane_edges[s] += sum(ps.chunk_n_valid(c)
-                                         for c in batches[s])
-                    lane_wall[s] += times[s]
-                save_base(base)
-                if straggler is not None:
+                _count_merge(pc, base, R)
+                if pc.emits_parts:
+                    parts = spans.to_host(parts)
+                    for ri, row in enumerate(ids):
+                        for s, cid in enumerate(row):
+                            if cid is not None:
+                                parts_by_chunk[cid] = parts[
+                                    s, ri, :host_blk[2][s, ri]]
+                r0 += sc
+            if backend == "shard_map":
+                # the merged carry leaves the mesh: what the job does next
+                # runs on one device, as after a sequential drive
+                base = jax.device_put(base, jax.devices()[0])
+        elif backend == "threads":
+            # S host workers fold their sub-streams concurrently through the
+            # shared compiled step (execution releases the GIL); chunk staging
+            # is serialized under one lock — the out-of-core stream's budget
+            # accounting and staging buffers are not thread-safe, and staging
+            # is a small fraction of a chunk's scan cost.
+            stage_lock = threading.Lock()
+            # lanes are mutable here: straggler handoff re-deals remaining
+            # chunks between merge boundaries (the sharding plan's own lists
+            # stay pristine in the chunk-granular modes; hub mode re-registers
+            # synthetic chunks, pin map updated in place)
+            lanes = [list(lane) for lane in ps.lanes]
+            pos = [0] * S  # per-lane cursor into its (possibly re-dealt) list
+            edges_done = 0  # edges committed through merges (checkpoint key)
+            consumer = (carry_consumer if carry_consumer is not None
+                        else f"parallel:{type(pc).__name__}")
+            store_cfg = dict(carry_config or {})
+            store_cfg.setdefault("super_chunk", str(super_chunk))
+            store_cfg.setdefault("shard", shard)
+
+            def lane_fold(lane_id, chunks, start, inject, shares):
+                lane_pc = pc.for_lane(shares[0][lane_id]) if shares else pc
+                local = start
+                t0 = time.perf_counter()
+                for cid in chunks:
+                    if inject is not None:
+                        inject.check(lane_id, cid)
+                    with stage_lock:
+                        ch = ps.chunk_for(cid, *extras)
+                    local, parts = lane_pc.step_chunk(
+                        local, ch.src, ch.dst, jnp.int32(ch.n_valid),
+                        *ch.extras)
+                    if parts is not None:
+                        parts_by_chunk[cid] = parts[: ch.n_valid]
+                lane_dev[lane_id] = _device_of(local)
+                return local, time.perf_counter() - t0
+
+            def save_base(carry_val):
+                if carry_store is not None:
+                    carry_store.save(carry_val, consumer=consumer,
+                                     config=store_cfg, stream_pos=edges_done)
+
+            def restore_base():
+                if carry_store is None:
+                    return base  # in-memory merge base == last commit point
+                restored, _ = carry_store.load(like=base, consumer=consumer,
+                                               config=store_cfg,
+                                               max_stream_pos=edges_done)
+                return restored
+
+            save_base(base)  # a lane can die before the first merge commits
+            sc_index = 0
+            with ThreadPoolExecutor(max_workers=S) as ex:
+                while any(pos[s] < len(lanes[s]) for s in range(S)):
+                    sc = ctl.next()
+                    batches = [lanes[s][pos[s]:pos[s] + sc] for s in range(S)]
+                    shares = _shares(pc, base, [
+                        sum(ps.chunk_n_valid(c) for c in b) for b in batches])
+                    futs = [ex.submit(lane_fold, s, batches[s], base,
+                                      lane_injector, shares)
+                            for s in range(S)]
+                    locals_: list = [None] * S
+                    times = [0.0] * S
+                    failed: list[int] = []
+                    for s, f in enumerate(futs):
+                        try:
+                            locals_[s], times[s] = f.result()
+                        except Exception as e:  # noqa: BLE001 — lane death
+                            if on_lane_failure != "replay":
+                                raise
+                            log.warning("ingest lane %d died mid-super-chunk "
+                                        "(%s); replaying its range", s, e)
+                            failed.append(s)
+                    for s in failed:
+                        # replay the dead lane's chunk range from the last
+                        # committed base into a surviving worker — the merge
+                        # below can't tell the difference (bit-identical)
+                        locals_[s], times[s] = ex.submit(
+                            lane_fold, s, batches[s], restore_base(),
+                            None, shares).result()
+                    prev = base
+                    base = pc.merge(locals_, base=prev)
+                    ctl.observe(prev, base)
+                    _count_merge(pc, base, max(len(b) for b in batches))
+                    edges_done += sum(ps.chunk_n_valid(cid)
+                                      for b in batches for cid in b)
                     for s in range(S):
-                        if batches[s]:
-                            # per-chunk time: lane *speed*, not workload
-                            straggler.record(sc_index,
-                                             times[s] / len(batches[s]),
-                                             shard=s)
-                    _handoff_lanes(ps, lanes, pos, straggler)
-                sc_index += 1
-    else:
-        raise ValueError(f"unknown backend {backend!r}")
+                        pos[s] += len(batches[s])
+                        lane_chunks[s] += len(batches[s])
+                        lane_edges[s] += sum(ps.chunk_n_valid(c)
+                                             for c in batches[s])
+                        lane_wall[s] += times[s]
+                    save_base(base)
+                    if straggler is not None:
+                        for s in range(S):
+                            if batches[s]:
+                                # per-chunk time: lane *speed*, not workload
+                                straggler.record(sc_index,
+                                                 times[s] / len(batches[s]),
+                                                 shard=s)
+                        _handoff_lanes(ps, lanes, pos, straggler)
+                    sc_index += 1
+        else:
+            raise ValueError(f"unknown backend {backend!r}")
 
-    if backend != "threads":  # lanes execute as one program per round
-        wall = time.perf_counter() - t_run
-        for s in range(S):
-            lane_chunks[s] = len(ps.lanes[s])
-            lane_edges[s] = sum(ps.chunk_n_valid(c) for c in ps.lanes[s])
-            lane_wall[s] = wall
-    merges = len(ctl.schedule)
-    _publish_stats(pc, IngestStats(
-        num_streams=S, shard=shard, backend=backend, super_chunk=super_chunk,
-        schedule=tuple(ctl.schedule),
-        lanes=tuple(LaneStats(chunks=lane_chunks[s], edges=lane_edges[s],
-                              merge_count=merges, wall_s=lane_wall[s],
-                              device=lane_dev[s])
-                    for s in range(S))))
+        if backend != "threads":  # lanes execute as one program per round
+            wall = time.perf_counter() - t_run
+            for s in range(S):
+                lane_chunks[s] = len(ps.lanes[s])
+                lane_edges[s] = sum(ps.chunk_n_valid(c) for c in ps.lanes[s])
+                lane_wall[s] = wall
+        merges = len(ctl.schedule)
+        _publish_stats(pc, IngestStats(
+            num_streams=S, shard=shard, backend=backend,
+            super_chunk=super_chunk,
+            schedule=tuple(ctl.schedule),
+            lanes=tuple(LaneStats(chunks=lane_chunks[s], edges=lane_edges[s],
+                                  merge_count=merges, wall_s=lane_wall[s],
+                                  device=lane_dev[s])
+                        for s in range(S))))
 
-    result = pc.finalize(base)
-    if not parts_by_chunk:
-        return None, result
-    if ps.shard == "hub":
-        # synthetic chunks carry their stream positions; scatter each
-        # folded chunk's results straight to position order
-        first = next(iter(parts_by_chunk.values()))
-        out = np.empty((stream.n_edges,), np.asarray(first).dtype)
-        for cid, p in parts_by_chunk.items():
-            posns = ps._chunk_pos[cid]
-            out[posns] = np.asarray(p)[: len(posns)]
-        return stream.scatter_back(jnp.asarray(out)), result
-    outs = [parts_by_chunk[cid][: ps.chunk_n_valid(cid)]
-            for cid in range(stream.n_chunks)]
-    parts = outs[0] if len(outs) == 1 else jnp.concatenate(outs)
-    return stream.scatter_back(parts), result
+        result = pc.finalize(base)
+        parts = None
+        if parts_by_chunk:
+            first = next(iter(parts_by_chunk.values()))
+            out = np.empty((stream.n_edges,), first.dtype)
+            for cid, p in parts_by_chunk.items():
+                out[ps.chunk_positions(cid)] = spans.to_host(p)
+            parts = stream.scatter_back(jnp.asarray(out))
+        return drive.wait_for((parts, result))
+
+
+def _shares(pc, base, demand) -> tuple:
+    """``(shares,)``, the consumer's per-lane shares for a super-step whose
+    lanes fold ``demand`` edges each from ``base``, or ``()`` when it
+    bounds nothing (:meth:`PartitionerCarry.lane_shares`)."""
+    shares = pc.lane_shares(base, demand)
+    return () if shares is None else (shares,)
+
+
+def _lane_step(pc, n_shares):
+    """:func:`_mask_inactive_step` for one lane, called as ``step(carry,
+    *shares, src, dst, n_valid, *extras)``: with a share, the consumer as
+    that lane folds it (:meth:`PartitionerCarry.for_lane`)."""
+
+    def step(carry, *args):
+        lane_pc = pc.for_lane(args[0]) if n_shares else pc
+        return _mask_inactive_step(lane_pc)(carry, *args[n_shares:])
+
+    return step
+
+
+def _count_merge(pc, carry, rounds: int) -> None:
+    """The lane counters of one merge: ``lanes.merges``, ``lanes.rounds``
+    (chunks each lane folded since the last merge) and
+    ``lanes.merge_bytes`` (what each lane hands to the merge)."""
+    spans.count("lanes.merges")
+    spans.count("lanes.rounds", rounds)
+    spans.count("lanes.merge_bytes", pc.merge_bytes(carry))
 
 
 def _handoff_lanes(ps, lanes, pos, straggler):
@@ -940,37 +996,96 @@ def _handoff_lanes_hub(ps, lanes, pos, ranges, plan):
                  receiver)
 
 
-def _make_super_step(pc, mesh, axis, R, base, n_ex):
+#: compiled shard_map super-steps, shared by every drive whose consumer
+#: differs only in its per-job arrays (see :func:`_super_step`)
+_SUPER_STEPS: dict = {}
+_SUPER_STEPS_MAX = 64
+
+
+def _split_consumer(pc):
+    """``(arrays, key)``: the consumer's per-job arrays (its attributes
+    that are arrays: Alg. 1's degree table, Alg. 3's cluster→partition
+    table and capacity) and a key of everything else it holds, its class
+    and that class's step and merge.  ``key`` is None when something it
+    holds cannot be hashed."""
+    arrays, rest = {}, []
+    for name, v in sorted(vars(pc).items()):
+        if isinstance(v, (jax.Array, np.ndarray)):
+            arrays[name] = v
+        else:
+            rest.append((name, v))
+    cls = type(pc)
+    key = (cls, cls.step_chunk, cls.merge_collective, tuple(rest))
+    try:
+        hash(key)
+    except TypeError:
+        return arrays, None
+    return arrays, key
+
+
+def _super_step(pc, mesh, axis, R, base, n_ex, n_shares=0):
+    """The jitted shard_map super-step of R rounds for ``pc``, called as
+    ``step(arrays, base, *shares, src, dst, nv, *extras)`` with ``arrays``
+    the consumer's per-job arrays (:func:`_split_consumer`).  One step
+    serves every later drive of a consumer that differs only in those
+    arrays, so a job whose shapes an earlier job had compiles nothing."""
+    arrays, key = _split_consumer(pc)
+    if key is not None:
+        key = (key, mesh, axis, R, n_ex, n_shares,
+               jax.tree_util.tree_structure(base))
+        step = _SUPER_STEPS.get(key)
+        if step is not None:
+            return step
+    step = _make_super_step(pc, tuple(arrays), mesh, axis, R, n_ex,
+                            n_shares)
+    if key is not None:
+        if len(_SUPER_STEPS) >= _SUPER_STEPS_MAX:
+            _SUPER_STEPS.pop(next(iter(_SUPER_STEPS)))
+        _SUPER_STEPS[key] = step
+    return step
+
+
+def _make_super_step(pc, names, mesh, axis, R, n_ex, n_shares=0):
     """Build the jitted shard_map super-step for R rounds: each device
     folds its lane's R chunks from the replicated base carry, then the
-    carries are merged by one collective per field.  Returns a callable
-    ``(base, src (S,R,B), dst, nv (S,R), *extras) -> (merged (S-stacked,
-    identical per lane — caller takes lane 0), parts (S, R, B))``."""
+    carries are merged by one collective per field.  ``names`` are the
+    consumer's array attributes, passed in as the step's first argument
+    (replicated) rather than captured.  Returns a callable ``(arrays,
+    base, *shares (S, ...), src (S,R,B), dst, nv (S,R), *extras) ->
+    (merged carry (replicated), parts (S, R, B))``; the device trace
+    names its program ``lanes_super_step``."""
     P = jax.sharding.PartitionSpec
     lane = P(axis)
+    # the step keeps no job's arrays alive
+    template = copy.copy(pc)
+    for name in names:
+        setattr(template, name, None)
 
-    step = _mask_inactive_step(pc)
-
-    def body(base_carry, src, dst, nv, *exs):
+    def lanes_super_step(arrays, base_carry, *args):
+        job = copy.copy(template)
+        for name, a in zip(names, arrays):
+            setattr(job, name, a)
+        shares = [x[0] for x in args[:n_shares]]
+        src, dst, nv, *exs = args[n_shares:]
+        step = _lane_step(job, n_shares)
         local = base_carry
         parts_rounds = []
         for r in range(R):
             local, parts = step(
-                local, src[0, r], dst[0, r], nv[0, r],
+                local, *shares, src[0, r], dst[0, r], nv[0, r],
                 *[e[0, r] for e in exs])
-            if pc.emits_parts:
+            if job.emits_parts:
                 parts_rounds.append(parts)
-        merged = pc.merge_collective(local, base_carry, axis)
-        merged = jax.tree_util.tree_map(lambda x: x[None], merged)
+        merged = job.merge_collective(local, base_carry, axis)
         if parts_rounds:
             return merged, jnp.stack(parts_rounds)[None]
         return merged, jnp.zeros((1, 1, 1), jnp.int32)
 
     return jax.jit(jax.shard_map(
-        body, mesh=mesh,
-        in_specs=(jax.tree_util.tree_map(lambda _: P(), base),
-                  lane, lane, lane) + (lane,) * n_ex,
-        out_specs=(jax.tree_util.tree_map(lambda _: lane, base), lane),
+        lanes_super_step, mesh=mesh,
+        in_specs=(P(), P()) + (lane,) * (n_shares + 3 + n_ex),
+        # every lane holds the same merged carry after the collectives
+        out_specs=(P(), lane),
         # the megakernels' interpret mode cannot carry varying-axis types
         # through its grid loop, so the body is not vma-checked
         check_vma=False,

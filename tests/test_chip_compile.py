@@ -11,6 +11,9 @@ side of a ladder boundary (about a second per compile):
 - forcing the old rung at that width is refused by the compiler, so the
   gate's byte count is tight, not merely safe.
 
+The four-lane S5P cell's shard_map super-steps also compile here for the
+2x2 host, their kernels inside, one all-reduce merge each.
+
 The topology is described inside a module fixture (never at import), and
 the persistent compilation cache is off around these compiles: a compile
 for a described chip is written to it but cannot be read back without one.
@@ -32,7 +35,7 @@ K_PARTS = 32
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache as cc
@@ -45,9 +48,14 @@ def one_chip():
     was_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was_on)
     cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _shape(sharding, shape, dtype=jnp.int32):
@@ -165,3 +173,59 @@ def test_assign_compiles_at_default_chunk(one_chip, k):
     args.append(_shape(one_chip, (1, W)))
     _compile(K._assign_call, *args, k=k, block=K.DEFAULT_BLOCK,
              interpret=False)
+
+
+def test_assign_with_per_partition_caps_compiles(one_chip):
+    """Alg. 3 as an ingest lane runs it: one capacity per partition."""
+    W = K.table_width(K_PARTS, "assign")
+    args = [_shape(one_chip, (3,))] + [_shape(one_chip, (CHUNK,))] * 6
+    args += [_shape(one_chip, (1, W))] * 2
+    _compile(K._assign_call, *args, k=K_PARTS, block=K.DEFAULT_BLOCK,
+             interpret=False)
+
+
+@pytest.mark.parametrize("consumer", ["alg1", "theta", "alg3"])
+def test_lane_super_steps_compile_on_a_2x2_mesh(topo, monkeypatch, consumer):
+    """The super-steps of the four-lane S5P cell (V = 65,536, k = 32,
+    65,536-edge chunks) for one lane per chip of a v5e 2x2: Alg. 1 on its
+    tiled rung over a lane's 4 chunks, the Theta sketch over 3 chunks of
+    2**18 pairs, Alg. 3 on its fused rung over 2 rounds, each lane under
+    its own per-partition capacities."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.core.clustering import ClusterCarry
+    from repro.core.cms import SketchCarry, suggest_params
+    from repro.core.postprocess import AssignCarry
+    from repro.streaming import parallel
+
+    resolve = K._resolve  # the kernels, not their interpreter, on the chip
+    monkeypatch.setattr(K, "_resolve", lambda b, n, i: resolve(b, n, False))
+    mesh = Mesh(np.asarray(topo.devices), ("streams",))
+    rep = NamedSharding(mesh, P())
+    by_lane = NamedSharding(mesh, P("streams"))
+    V, S = 1 << 16, 4
+    if consumer == "alg1":
+        pc = ClusterCarry(jnp.zeros(V, jnp.int32), V, xi=27, kappa=56875,
+                          use_kernel=True)
+        R, B, extras, shares = 4, CHUNK, (), ()
+    elif consumer == "theta":
+        w, d = suggest_params()
+        pc = SketchCarry(w * 108, d)
+        R, B, extras, shares = 3, 1 << 18, (), ()
+    else:
+        pc = AssignCarry(K_PARTS, 28438, jnp.zeros(11819, jnp.int32),
+                         use_kernel=True)
+        R, B, extras = 2, CHUNK, (jnp.bool_, jnp.int32, jnp.int32)
+        shares = (_shape(by_lane, (S, K_PARTS)),)
+    arrays, _ = parallel._split_consumer(pc)
+    step = parallel._make_super_step(pc, tuple(arrays), mesh, "streams", R,
+                                     len(extras), len(shares))
+    args = [tuple(_shape(rep, a.shape, a.dtype) for a in arrays.values()),
+            jax.tree_util.tree_map(lambda x: _shape(rep, x.shape, x.dtype),
+                                   pc.init()), *shares]
+    args += [_shape(by_lane, (S, R, B))] * 2 + [_shape(by_lane, (S, R))]
+    args += [_shape(by_lane, (S, R, B), dt) for dt in extras]
+    text = step.lower(*args).compile().as_text()
+    assert " all-reduce(" in text
+    assert ("tpu_custom_call" in text) == (consumer != "theta")

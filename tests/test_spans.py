@@ -187,3 +187,88 @@ def test_s5p_touch_up_is_a_phase_of_the_job():
         "s5p.game", "s5p.alg3", "host.pull", "s5p.touch_up"]
     pulls = phases[-1][1]
     assert len(pulls) >= 4 and all(p == ("host.pull", []) for p in pulls)
+
+
+def _lanes_job(monkeypatch, backend="vmap"):
+    """One S5P job with four lanes on ``backend`` at scale 11 (several
+    chunks per lane, and a touch-up that moves clusters); returns the
+    output and the stats of every lane drive it made."""
+    from repro.core import S5PConfig, s5p_partition
+    from repro.graphs.generators import rmat_graph
+    from repro.streaming import parallel
+
+    drives = []
+    publish = parallel._publish_stats
+
+    def keep(pc, stats):
+        drives.append(stats)
+        return publish(pc, stats)
+
+    monkeypatch.setattr(parallel, "_publish_stats", keep)
+    monkeypatch.setattr(parallel, "_resolve_backend", lambda b, S: backend)
+    src, dst = rmat_graph(11, seed=3)[:2]
+    cfg = S5PConfig(k=32, chunk_size=1024, num_streams=4, super_chunk="auto")
+    out = s5p_partition(src, dst, 1 << 11, cfg)
+    return out, [d for d in drives if d.num_streams > 1], src.size
+
+
+def test_lane_spans_and_counters_are_under_the_job(monkeypatch):
+    spans.enable()
+    out, drives, _ = _lanes_job(monkeypatch)
+    recs = spans.records()
+    (job, phases), = [t for t in _tree(recs) if t[0] == "s5p.job"]
+    by_phase = {name: kids for name, kids in phases}
+    # Alg. 3's lanes read the merge base for their capacity shares
+    for phase, stage in (("s5p.alg1", []), ("s5p.alg3", [("host.pull", [])])):
+        drive, = [kids for name, kids in by_phase[phase]
+                  if name == "lanes.drive"]
+        assert ("lanes.stage", stage) in drive
+    assert "s5p.touch_up" in by_phase
+    root = next(r for r in recs if r.name == "s5p.job" and r.parent is None)
+    counts = spans.counters(root.id)
+    assert len([r for r in recs if r.name == "lanes.drive"]) == len(drives)
+    assert counts["lanes.merges"] == sum(len(d.schedule) for d in drives)
+    assert counts["lanes.rounds"] == sum(max(l.chunks for l in d.lanes)
+                                         for d in drives)
+    assert counts["lanes.merge_bytes"] > 0
+    tu = out.aux["touch_up"]
+    assert tu["moved_clusters"] > 0
+    assert counts["s5p.touch_up.contested"] == tu["contested_clusters"]
+    assert counts["s5p.touch_up.moved"] == tu["moved_clusters"]
+    assert counts["s5p.touch_up.replayed_edges"] == tu["replayed_edges"]
+
+
+def test_lane_metrics_read_a_recorded_job(monkeypatch):
+    import sys
+    from pathlib import Path
+
+    root = str(Path(__file__).resolve().parents[1])
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from bench import harness, trace
+
+    names = ("lanes_ingest_s", "lane_stage_s", "lane_merge_s",
+             "lane_merge_mib", "touch_up_s", "lanes_game_s")
+    readers = {n: harness.load("metrics", n).read for n in names}
+    empty = harness.RunView(spans={}, compiles=0, jobs_in_window=1,
+                            edges_in_window=1, k=32, peaks={})
+    assert {n: r(empty) for n, r in readers.items()} == dict.fromkeys(names)
+
+    spans.enable()
+    _, _, E = _lanes_job(monkeypatch)
+    # a device trace of the job: one merge all-reduce on each of two
+    # devices, and one op of another program
+    op = "%psum.7 = s32[32]{0:T(128)} all-reduce(%fusion.4), channel_id=1"
+    tr = trace.Trace(device_ops={
+        "/device:TPU:0": [(op, 0, 3e6, "jit_lanes_super_step(7)"),
+                          ("%fusion.1 = s32[8] fusion()", 3e6, 9e6,
+                           "jit_lanes_super_step(7)")],
+        "/device:TPU:1": [(op, 0, 1e6, "jit_lanes_super_step(7)"),
+                          (op, 1e6, 2e6, "jit_other(3)")]},
+        window=(0.0, 1e7))
+    run = harness.RunView(spans={}, compiles=0, jobs_in_window=1,
+                          edges_in_window=E, k=32, peaks={}, trace=tr,
+                          edges_traced=E)
+    got = {n: r(run) for n, r in readers.items()}
+    assert all(isinstance(v, float) and v > 0 for v in got.values()), got
+    assert got["lane_merge_s"] == pytest.approx((3e-3 + 1e-3) / 2)
