@@ -272,3 +272,47 @@ def test_lane_metrics_read_a_recorded_job(monkeypatch):
     got = {n: r(run) for n, r in readers.items()}
     assert all(isinstance(v, float) and v > 0 for v in got.values()), got
     assert got["lane_merge_s"] == pytest.approx((3e-3 + 1e-3) / 2)
+
+
+def test_game_counts_its_batch_updates_and_adjacency_entries(monkeypatch):
+    """``game.adj_entries`` is rounds × (2P + the call's chunk padding):
+    each round's windows read every real pair from both sides once;
+    ``game.batch_updates`` is rounds × the batches of a round."""
+    from repro.core import S5PConfig, s5p_partition
+    from repro.core import game as game_mod
+    from repro.graphs.generators import rmat_graph
+
+    calls = []
+    run = game_mod.run_game
+
+    def keep(inputs, n_clusters, **kw):
+        res = run(inputs, n_clusters, **kw)
+        calls.append((inputs, n_clusters, kw["batch_size"], res))
+        return res
+
+    monkeypatch.setattr(game_mod, "run_game", keep)
+    src, dst = rmat_graph(10, seed=3)[:2]
+    spans.enable()
+    s5p_partition(src, dst, 1 << 10, S5PConfig(k=8, chunk_size=2048))
+    root = next(r for r in spans.records() if r.name == "s5p.job")
+    (inputs, C, bs, res), = calls
+    pa, pb = np.asarray(inputs.pair_a), np.asarray(inputs.pair_b)
+    real = (pa < C) & (pb < C)
+    P = int(real.sum())
+    assert P > 0
+    # rows' entry counts, and the windows of one round: leaders from 0,
+    # cut at n_head; followers from n_head
+    row_len = np.bincount(np.concatenate([pa[real], pb[real]]), minlength=C)
+    rowptr = np.concatenate([[0], np.cumsum(row_len)])
+    H = inputs.n_head
+    nb_h, nb_t = max(1, -(-H // bs)), max(1, -(-(C - H) // bs))
+    lo = np.concatenate([np.arange(nb_h) * bs, H + np.arange(nb_t) * bs])
+    hi = np.minimum(lo + bs, np.r_[np.full(nb_h, H), np.full(nb_t, C)])
+    n = rowptr[hi] - rowptr[lo]
+    assert n.sum() == 2 * P
+    L = game_mod.ADJ_CHUNK
+    pad = int(np.sum(-(-n // L) * L - n))
+    rounds = int(res.rounds)
+    counts = spans.counters(root.id)
+    assert counts["game.adj_entries"] == rounds * (2 * P + pad)
+    assert counts["game.batch_updates"] == rounds * (nb_h + nb_t)
